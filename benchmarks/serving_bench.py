@@ -39,6 +39,12 @@ on every row, mesh rows included.
 Every pallas row records ``speedup_vs_einsum`` (its tokens/s over the
 matching einsum row's): < 1 on CPU where the kernel runs in interpret
 mode, the number to watch on TPU.
+
+Off the chip path: this bench is a CPU trajectory.  Its mesh rows re-exec
+the script as a forced-host CPU child after the parent has touched JAX,
+and on a TPU host a parent holds the chip, so such a row would measure
+the CPU under a mesh row's name.  On the chip, run ``chip_smoke.py``
+(one process) instead.
 """
 
 from __future__ import annotations

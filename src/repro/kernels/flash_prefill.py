@@ -44,9 +44,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0].astype(jnp.float32)          # (Bq, dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)          # (Bk, dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)          # (Bk, dv)
+        q = q_ref[0].astype(jnp.float32)                # (Bq, dh)
+        k = k_ref[0].astype(jnp.float32)                # (Bk, dh)
+        v = v_ref[0].astype(jnp.float32)                # (Bk, dv)
         s = (q @ k.T) * scale                           # (Bq, Bk)
         qp = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         kp = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
@@ -69,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     @pl.when(i_k == n_k - 1)
     def _finish():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, :, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -109,23 +109,28 @@ def flash_prefill_attention(q, k, v, *, causal: bool = True,
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, window=window,
         block_q=bq, block_k=bk, n_k=n_k, seq_len=T)
+    # Heads fold into the lane axis — (B, T, H*dh), a free reshape — so a
+    # (1, Bq, dh) block at index (b, iq, h) tiles rows on sublanes and one
+    # head on lanes; a (1, Bq, 1, dh) block of the 4-D array is refused by
+    # Mosaic.  On TPU this needs dh, dv % 128 == 0 (or a single head).
+    lanes = lambda x: x.reshape(B, Tp, -1)
     out = pl.pallas_call(
         kernel,
         grid=(B, H, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, dh), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, dh),
-                         lambda b, h, iq, ik, qpk=qpk: (b, ik, h // qpk, 0)),
-            pl.BlockSpec((1, bk, 1, dv),
-                         lambda b, h, iq, ik, qpv=qpv: (b, ik, h // qpv, 0)),
+            pl.BlockSpec((1, bq, dh), lambda b, h, iq, ik: (b, iq, h)),
+            pl.BlockSpec((1, bk, dh),
+                         lambda b, h, iq, ik, qpk=qpk: (b, ik, h // qpk)),
+            pl.BlockSpec((1, bk, dv),
+                         lambda b, h, iq, ik, qpv=qpv: (b, ik, h // qpv)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, dv), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Tp, H, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, dv), lambda b, h, iq, ik: (b, iq, h)),
+        out_shape=jax.ShapeDtypeStruct((B, Tp, H * dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(lanes(q), lanes(k), lanes(v)).reshape(B, Tp, H, dv)
     return out[:, :T] if Tp != T else out

@@ -65,7 +65,7 @@ def attend_block(q, k, zv, cos, sin, bias, *, scale, s, qpk, dh,
     scores = jnp.concatenate(
         [qg[i] @ kr[:, i, :].T for i in range(s)], axis=0
     ) * scale                                          # (Hg, Sb)
-    scores = scores + bias[None, :]
+    scores = scores + bias                             # bias: (1, Sb)
 
     m_prev = m_ref[:, 0]
     l_prev = l_ref[:, 0]
@@ -111,19 +111,19 @@ def _kernel(q_ref, zk_ref, zv_ref, rk_ref, kn_ref, cos_ref, sin_ref, bias_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bias = bias_ref[0].astype(jnp.float32)
+    bias = bias_ref[0, 0].astype(jnp.float32)          # (1, Sb)
 
     # Skip fully-masked key tiles (empty ring regions, internal tail
     # padding): no MXU work, no softmax-state update.
     @pl.when(jnp.max(bias) > NEG_INF * 0.5)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (Hg, dh), Hg = s*qpk
-        zk = zk_ref[0, :, 0].astype(jnp.float32)       # (Sb, r_k)
+        zk = zk_ref[0].astype(jnp.float32)             # (Sb, r_k)
         rk = rk_ref[0].astype(jnp.float32)             # (r_k, s*dh)
         k = zk @ rk                                    # (Sb, s*dh)  reconstruct
         sb = k.shape[0]
         k = maybe_knorm(k.reshape(sb, s, dh), kn_ref, apply_knorm, norm_eps)
-        attend_block(q, k, zv_ref[0, :, 0].astype(jnp.float32),
+        attend_block(q, k, zv_ref[0].astype(jnp.float32),
                      cos_ref[0].astype(jnp.float32),
                      sin_ref[0].astype(jnp.float32), bias,
                      scale=scale, s=s, qpk=qpk, dh=dh,
@@ -148,6 +148,27 @@ def pad_ring(bias: jax.Array, block_s: int, *arrays: jax.Array):
         jnp.pad(a, ((0, 0), (0, Sp - S)) + ((0, 0),) * (a.ndim - 2))
         for a in arrays)
     return Sp, bias, *arrays
+
+
+def lane_view(x: jax.Array) -> jax.Array:
+    """(B, S, G, r) -> (B, S, G*r), a free reshape.  Blocks of (1, Sb, r)
+    at index (b, i, g) then put the rank on the 128-lane axis and the key
+    rows on sublanes, which Mosaic tiles; a block holding one element of
+    the group axis just ahead of the lanes is refused.  On TPU this needs
+    r % 128 == 0 (or a single group)."""
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+def tile_bias(bias: jax.Array, bs: int) -> jax.Array:
+    """Additive bias (B, [nq,] n_tiles*bs) -> (B, n_tiles, rows, bs) with
+    rows = nq (1 for single-query).  A (1, 1, rows, bs) block at index
+    (b, i, 0, 0) then spans the array's last two dims whole, which Mosaic
+    accepts for any tile width; a (1, bs) block of a (B, S) bias is
+    refused, and a lane block of a (B, 1, S) bias needs bs % 128 == 0."""
+    if bias.ndim == 2:
+        bias = bias[:, None]
+    B, rows, cols = bias.shape
+    return bias.reshape(B, rows, cols // bs, bs).transpose(0, 2, 1, 3)
 
 
 def lse_outputs(B, G, rows, rv, dtype, return_lse, prefetch=False):
@@ -213,13 +234,13 @@ def latent_decode_attention(q, zk, zv, r_k, cos, sin, bias, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, Hg, dh), lambda b, g, i: (b, g, 0, 0)),
-            pl.BlockSpec((1, bs, 1, rk), lambda b, g, i: (b, i, g, 0)),
-            pl.BlockSpec((1, bs, 1, rv), lambda b, g, i: (b, i, g, 0)),
+            pl.BlockSpec((1, bs, rk), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, bs, rv), lambda b, g, i: (b, i, g)),
             pl.BlockSpec((1, rk, sdh), lambda b, g, i: (g, 0, 0)),
             pl.BlockSpec((1, dh), lambda b, g, i: (0, 0)),
             pl.BlockSpec((1, bs, half), lambda b, g, i: (b, i, 0)),
             pl.BlockSpec((1, bs, half), lambda b, g, i: (b, i, 0)),
-            pl.BlockSpec((1, bs), lambda b, g, i: (b, i)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b, g, i: (b, i, 0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -229,7 +250,7 @@ def latent_decode_attention(q, zk, zv, r_k, cos, sin, bias, *,
             pltpu.VMEM((Hg, rv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, zk, zv, r_k, kn, cos, sin, bias)
+    )(q, lane_view(zk), lane_view(zv), r_k, kn, cos, sin, tile_bias(bias, bs))
 
 
 # ---------------------------------------------------------------------------
@@ -293,17 +314,17 @@ def _mq_kernel(q_ref, zk_ref, zv_ref, rk_ref, kn_ref, cos_ref, sin_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bias = bias_ref[0].astype(jnp.float32)             # (nq, Sb)
+    bias = bias_ref[0, 0].astype(jnp.float32)          # (nq, Sb)
 
     @pl.when(jnp.max(bias) > NEG_INF * 0.5)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (nq*Hg, dh)
-        zk = zk_ref[0, :, 0].astype(jnp.float32)
+        zk = zk_ref[0].astype(jnp.float32)
         rk = rk_ref[0].astype(jnp.float32)
         k = zk @ rk
         sb = k.shape[0]
         k = maybe_knorm(k.reshape(sb, s, dh), kn_ref, apply_knorm, norm_eps)
-        attend_block_mq(q, k, zv_ref[0, :, 0].astype(jnp.float32),
+        attend_block_mq(q, k, zv_ref[0].astype(jnp.float32),
                         cos_ref[0].astype(jnp.float32),
                         sin_ref[0].astype(jnp.float32), bias,
                         scale=scale, nq=nq, s=s, qpk=qpk, dh=dh,
@@ -372,13 +393,13 @@ def latent_decode_attention_mq(q, zk, zv, r_k, cos, sin, bias, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, QHg, dh), lambda b, g, i: (b, g, 0, 0)),
-            pl.BlockSpec((1, bs, 1, rk), lambda b, g, i: (b, i, g, 0)),
-            pl.BlockSpec((1, bs, 1, rv), lambda b, g, i: (b, i, g, 0)),
+            pl.BlockSpec((1, bs, rk), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, bs, rv), lambda b, g, i: (b, i, g)),
             pl.BlockSpec((1, rk, sdh), lambda b, g, i: (g, 0, 0)),
             pl.BlockSpec((1, dh), lambda b, g, i: (0, 0)),
             pl.BlockSpec((1, bs, half), lambda b, g, i: (b, i, 0)),
             pl.BlockSpec((1, bs, half), lambda b, g, i: (b, i, 0)),
-            pl.BlockSpec((1, nq, bs), lambda b, g, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, nq, bs), lambda b, g, i: (b, i, 0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -388,7 +409,7 @@ def latent_decode_attention_mq(q, zk, zv, r_k, cos, sin, bias, *,
             pltpu.VMEM((QHg, rv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, zk, zv, r_k, kn, cos, sin, bias)
+    )(q, lane_view(zk), lane_view(zv), r_k, kn, cos, sin, tile_bias(bias, bs))
 
 
 # ---------------------------------------------------------------------------
@@ -422,7 +443,7 @@ def _paged_kernel(ptab_ref, q_ref, zk_ref, zv_ref, zks_ref, zvs_ref, rk_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bias = bias_ref[0].astype(jnp.float32)
+    bias = bias_ref[0, 0].astype(jnp.float32)          # (1, ps)
     is_self = i_s == n_s - 1
 
     # Same fully-masked-tile skip as the ring kernel: unmapped slot-pages
@@ -431,10 +452,8 @@ def _paged_kernel(ptab_ref, q_ref, zk_ref, zv_ref, zks_ref, zvs_ref, rk_ref,
     @pl.when(jnp.max(bias) > NEG_INF * 0.5)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)
-        zk = jnp.where(is_self, zks_ref[0, :, 0],
-                       zk_ref[0, :, 0]).astype(jnp.float32)
-        zv = jnp.where(is_self, zvs_ref[0, :, 0],
-                       zv_ref[0, :, 0]).astype(jnp.float32)
+        zk = jnp.where(is_self, zks_ref[0], zk_ref[0]).astype(jnp.float32)
+        zv = jnp.where(is_self, zvs_ref[0], zv_ref[0]).astype(jnp.float32)
         rk = rk_ref[0].astype(jnp.float32)
         k = zk @ rk
         sb = k.shape[0]
@@ -486,7 +505,7 @@ def latent_decode_attention_paged(ptab, q, zk, zv, r_k, zk_self, zv_self,
     def pool_map(b, g, i, pt):
         # Clamped on the self step (i == n_sp): the DMA'd page is unused
         # there (the kernel reads the self tile), it just must be in range.
-        return (pt[b, jnp.minimum(i, n_sp - 1)], 0, g, 0)
+        return (pt[b, jnp.minimum(i, n_sp - 1)], 0, g)
 
     grid = (B, G, n_s)
     kernel = functools.partial(
@@ -499,15 +518,15 @@ def latent_decode_attention_paged(ptab, q, zk, zv, r_k, zk_self, zv_self,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, Hg, dh), lambda b, g, i, pt: (b, g, 0, 0)),
-            pl.BlockSpec((1, ps, 1, rk), pool_map),
-            pl.BlockSpec((1, ps, 1, rv), pool_map),
-            pl.BlockSpec((1, ps, 1, rk), lambda b, g, i, pt: (b, 0, g, 0)),
-            pl.BlockSpec((1, ps, 1, rv), lambda b, g, i, pt: (b, 0, g, 0)),
+            pl.BlockSpec((1, ps, rk), pool_map),
+            pl.BlockSpec((1, ps, rv), pool_map),
+            pl.BlockSpec((1, ps, rk), lambda b, g, i, pt: (b, 0, g)),
+            pl.BlockSpec((1, ps, rv), lambda b, g, i, pt: (b, 0, g)),
             pl.BlockSpec((1, rk, sdh), lambda b, g, i, pt: (g, 0, 0)),
             pl.BlockSpec((1, dh), lambda b, g, i, pt: (0, 0)),
             pl.BlockSpec((1, ps, half), lambda b, g, i, pt: (b, i, 0)),
             pl.BlockSpec((1, ps, half), lambda b, g, i, pt: (b, i, 0)),
-            pl.BlockSpec((1, ps), lambda b, g, i, pt: (b, i)),
+            pl.BlockSpec((1, 1, 1, ps), lambda b, g, i, pt: (b, i, 0, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -520,7 +539,8 @@ def latent_decode_attention_paged(ptab, q, zk, zv, r_k, zk_self, zv_self,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(ptab, q, zk, zv, zk_self, zv_self, r_k, kn, cos, sin, bias)
+    )(ptab, q, lane_view(zk), lane_view(zv), lane_view(zk_self),
+      lane_view(zv_self), r_k, kn, cos, sin, tile_bias(bias, ps))
 
 
 def _mq_paged_kernel(ptab_ref, q_ref, zk_ref, zv_ref, zks_ref, zvs_ref,
@@ -536,16 +556,14 @@ def _mq_paged_kernel(ptab_ref, q_ref, zk_ref, zv_ref, zks_ref, zvs_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bias = bias_ref[0].astype(jnp.float32)             # (nq, ps)
+    bias = bias_ref[0, 0].astype(jnp.float32)          # (nq, ps)
     is_self = i_s >= n_sp
 
     @pl.when(jnp.max(bias) > NEG_INF * 0.5)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (nq*Hg, dh)
-        zk = jnp.where(is_self, zks_ref[0, :, 0],
-                       zk_ref[0, :, 0]).astype(jnp.float32)
-        zv = jnp.where(is_self, zvs_ref[0, :, 0],
-                       zv_ref[0, :, 0]).astype(jnp.float32)
+        zk = jnp.where(is_self, zks_ref[0], zk_ref[0]).astype(jnp.float32)
+        zv = jnp.where(is_self, zvs_ref[0], zv_ref[0]).astype(jnp.float32)
         rk = rk_ref[0].astype(jnp.float32)
         k = zk @ rk
         sb = k.shape[0]
@@ -596,11 +614,11 @@ def latent_decode_attention_mq_paged(ptab, q, zk, zv, r_k, zk_self, zv_self,
     n_s = n_sp + n_st
 
     def pool_map(b, g, i, pt):
-        return (pt[b, jnp.minimum(i, n_sp - 1)], 0, g, 0)
+        return (pt[b, jnp.minimum(i, n_sp - 1)], 0, g)
 
     def self_map(b, g, i, pt):
         # Before the self region this indexes tile 0 (DMA'd but unused).
-        return (b, jnp.maximum(i - n_sp, 0), g, 0)
+        return (b, jnp.maximum(i - n_sp, 0), g)
 
     grid = (B, G, n_s)
     kernel = functools.partial(
@@ -614,15 +632,15 @@ def latent_decode_attention_mq_paged(ptab, q, zk, zv, r_k, zk_self, zv_self,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, QHg, dh), lambda b, g, i, pt: (b, g, 0, 0)),
-            pl.BlockSpec((1, ps, 1, rk), pool_map),
-            pl.BlockSpec((1, ps, 1, rv), pool_map),
-            pl.BlockSpec((1, ps, 1, rk), self_map),
-            pl.BlockSpec((1, ps, 1, rv), self_map),
+            pl.BlockSpec((1, ps, rk), pool_map),
+            pl.BlockSpec((1, ps, rv), pool_map),
+            pl.BlockSpec((1, ps, rk), self_map),
+            pl.BlockSpec((1, ps, rv), self_map),
             pl.BlockSpec((1, rk, sdh), lambda b, g, i, pt: (g, 0, 0)),
             pl.BlockSpec((1, dh), lambda b, g, i, pt: (0, 0)),
             pl.BlockSpec((1, ps, half), lambda b, g, i, pt: (b, i, 0)),
             pl.BlockSpec((1, ps, half), lambda b, g, i, pt: (b, i, 0)),
-            pl.BlockSpec((1, nq, ps), lambda b, g, i, pt: (b, 0, i)),
+            pl.BlockSpec((1, 1, nq, ps), lambda b, g, i, pt: (b, i, 0, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -635,4 +653,5 @@ def latent_decode_attention_mq_paged(ptab, q, zk, zv, r_k, zk_self, zv_self,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(ptab, q, zk, zv, zk_self, zv_self, r_k, kn, cos, sin, bias)
+    )(ptab, q, lane_view(zk), lane_view(zv), lane_view(zk_self),
+      lane_view(zv_self), r_k, kn, cos, sin, tile_bias(bias, ps))

@@ -17,23 +17,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.latent_decode import (attend_block, attend_block_mq,
                                          finish_tile, knorm_operand,
-                                         lse_outputs, maybe_knorm, pad_ring,
-                                         pad_ring_mq, split_out_refs)
+                                         lane_view, lse_outputs, maybe_knorm,
+                                         pad_ring, pad_ring_mq,
+                                         split_out_refs, tile_bias)
 
 NEG_INF = -1e30
 
 
-def _dequant(q_ref, s_ref):
-    """int8 latents x per-token/per-group scales -> f32 tile in VMEM."""
-    return (q_ref[0, :, 0].astype(jnp.float32)
-            * s_ref[0, :, 0][:, None].astype(jnp.float32))
+def _dequant(q_ref, s_ref, g):
+    """int8 latents x per-token/per-group scales -> f32 tile in VMEM.
+
+    q_ref: (1, Sb, r) — this group's lanes of the (B, S, G*r) view;
+    s_ref: (1, Sb, G) — every group's scales (a (1, Sb, 1) block of the
+    (B, S, G) scales is refused by Mosaic), so this group's column is
+    picked out by an exact one-hot select-and-sum (``g`` is the grid's
+    group index, read outside any ``pl.when`` body)."""
+    sc = s_ref[0].astype(jnp.float32)                      # (Sb, G)
+    col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1) == g
+    sc = jnp.sum(jnp.where(col, sc, 0.0), axis=1, keepdims=True)
+    return q_ref[0].astype(jnp.float32) * sc
 
 
 def _kernel(q_ref, zkq_ref, zks_ref, zvq_ref, zvs_ref, rk_ref, kn_ref,
             cos_ref, sin_ref, bias_ref, o_ref, *rest,
             scale, s, qpk, dh, n_s, apply_knorm, norm_eps,
             return_lse=False):
-    i_s = pl.program_id(2)
+    g, i_s = pl.program_id(1), pl.program_id(2)
     mo_ref, lo_ref, m_ref, l_ref, acc_ref = split_out_refs(rest, return_lse)
 
     @pl.when(i_s == 0)
@@ -42,17 +51,17 @@ def _kernel(q_ref, zkq_ref, zks_ref, zvq_ref, zvs_ref, rk_ref, kn_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bias = bias_ref[0].astype(jnp.float32)
+    bias = bias_ref[0, 0].astype(jnp.float32)          # (1, Sb)
 
     @pl.when(jnp.max(bias) > NEG_INF * 0.5)       # skip fully-masked tiles
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                  # (Hg, dh)
-        zk = _dequant(zkq_ref, zks_ref)                      # (Sb, r_k)
+        zk = _dequant(zkq_ref, zks_ref, g)                   # (Sb, r_k)
         rk = rk_ref[0].astype(jnp.float32)
         k = zk @ rk
         sb = k.shape[0]
         k = maybe_knorm(k.reshape(sb, s, dh), kn_ref, apply_knorm, norm_eps)
-        attend_block(q, k, _dequant(zvq_ref, zvs_ref),
+        attend_block(q, k, _dequant(zvq_ref, zvs_ref, g),
                      cos_ref[0].astype(jnp.float32),
                      sin_ref[0].astype(jnp.float32), bias,
                      scale=scale, s=s, qpk=qpk, dh=dh,
@@ -97,15 +106,15 @@ def latent_decode_attention_quant(q, zk_q, zk_scale, zv_q, zv_scale, r_k,
         grid=(B, G, n_s),
         in_specs=[
             pl.BlockSpec((1, 1, Hg, dh), lambda b, g, i: (b, g, 0, 0)),
-            pl.BlockSpec((1, bs, 1, rk), lambda b, g, i: (b, i, g, 0)),
-            pl.BlockSpec((1, bs, 1), lambda b, g, i: (b, i, g)),
-            pl.BlockSpec((1, bs, 1, rv), lambda b, g, i: (b, i, g, 0)),
-            pl.BlockSpec((1, bs, 1), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, bs, rk), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, bs, G), lambda b, g, i: (b, i, 0)),
+            pl.BlockSpec((1, bs, rv), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, bs, G), lambda b, g, i: (b, i, 0)),
             pl.BlockSpec((1, rk, sdh), lambda b, g, i: (g, 0, 0)),
             pl.BlockSpec((1, dh), lambda b, g, i: (0, 0)),
             pl.BlockSpec((1, bs, half), lambda b, g, i: (b, i, 0)),
             pl.BlockSpec((1, bs, half), lambda b, g, i: (b, i, 0)),
-            pl.BlockSpec((1, bs), lambda b, g, i: (b, i)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b, g, i: (b, i, 0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -115,14 +124,15 @@ def latent_decode_attention_quant(q, zk_q, zk_scale, zv_q, zv_scale, r_k,
             pltpu.VMEM((Hg, rv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, zk_q, zk_scale, zv_q, zv_scale, r_k, kn, cos, sin, bias)
+    )(q, lane_view(zk_q), zk_scale, lane_view(zv_q), zv_scale, r_k, kn, cos,
+      sin, tile_bias(bias, bs))
 
 
 def _mq_kernel_q(q_ref, zkq_ref, zks_ref, zvq_ref, zvs_ref, rk_ref, kn_ref,
                  cos_ref, sin_ref, bias_ref, o_ref, *rest,
                  scale, nq, s, qpk, dh, n_s, apply_knorm, norm_eps,
                  return_lse=False):
-    i_s = pl.program_id(2)
+    g, i_s = pl.program_id(1), pl.program_id(2)
     mo_ref, lo_ref, m_ref, l_ref, acc_ref = split_out_refs(rest, return_lse)
 
     @pl.when(i_s == 0)
@@ -131,17 +141,17 @@ def _mq_kernel_q(q_ref, zkq_ref, zks_ref, zvq_ref, zvs_ref, rk_ref, kn_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bias = bias_ref[0].astype(jnp.float32)             # (nq, Sb)
+    bias = bias_ref[0, 0].astype(jnp.float32)          # (nq, Sb)
 
     @pl.when(jnp.max(bias) > NEG_INF * 0.5)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (nq*Hg, dh)
-        zk = _dequant(zkq_ref, zks_ref)
+        zk = _dequant(zkq_ref, zks_ref, g)
         rk = rk_ref[0].astype(jnp.float32)
         k = zk @ rk
         sb = k.shape[0]
         k = maybe_knorm(k.reshape(sb, s, dh), kn_ref, apply_knorm, norm_eps)
-        attend_block_mq(q, k, _dequant(zvq_ref, zvs_ref),
+        attend_block_mq(q, k, _dequant(zvq_ref, zvs_ref, g),
                         cos_ref[0].astype(jnp.float32),
                         sin_ref[0].astype(jnp.float32), bias,
                         scale=scale, nq=nq, s=s, qpk=qpk, dh=dh,
@@ -193,15 +203,15 @@ def latent_decode_attention_mq_quant(q, zk_q, zk_scale, zv_q, zv_scale, r_k,
         grid=(B, G, n_s),
         in_specs=[
             pl.BlockSpec((1, 1, QHg, dh), lambda b, g, i: (b, g, 0, 0)),
-            pl.BlockSpec((1, bs, 1, rk), lambda b, g, i: (b, i, g, 0)),
-            pl.BlockSpec((1, bs, 1), lambda b, g, i: (b, i, g)),
-            pl.BlockSpec((1, bs, 1, rv), lambda b, g, i: (b, i, g, 0)),
-            pl.BlockSpec((1, bs, 1), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, bs, rk), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, bs, G), lambda b, g, i: (b, i, 0)),
+            pl.BlockSpec((1, bs, rv), lambda b, g, i: (b, i, g)),
+            pl.BlockSpec((1, bs, G), lambda b, g, i: (b, i, 0)),
             pl.BlockSpec((1, rk, sdh), lambda b, g, i: (g, 0, 0)),
             pl.BlockSpec((1, dh), lambda b, g, i: (0, 0)),
             pl.BlockSpec((1, bs, half), lambda b, g, i: (b, i, 0)),
             pl.BlockSpec((1, bs, half), lambda b, g, i: (b, i, 0)),
-            pl.BlockSpec((1, nq, bs), lambda b, g, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, nq, bs), lambda b, g, i: (b, i, 0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -211,4 +221,5 @@ def latent_decode_attention_mq_quant(q, zk_q, zk_scale, zv_q, zv_scale, r_k,
             pltpu.VMEM((QHg, rv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, zk_q, zk_scale, zv_q, zv_scale, r_k, kn, cos, sin, bias)
+    )(q, lane_view(zk_q), zk_scale, lane_view(zv_q), zv_scale, r_k, kn, cos,
+      sin, tile_bias(bias, bs))

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
@@ -194,9 +193,9 @@ def _shard_kernel_call(mesh, B: int, main, main_spec, slot, repl, body):
         o, m, l = body(main_l, slot_l, repl_l, self_on)
         return _merge_partial_softmax(o, m, l)
 
-    return shard_map(wrapped, mesh, in_specs=in_specs,
-                     out_specs=P(batch, None, None, None),
-                     check_rep=False)(main, slot, repl)
+    return jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(batch, None, None, None),
+                         check_vma=False)(main, slot, repl)
 
 
 def _mask_self_cols(bias, self_on, start):
@@ -708,15 +707,25 @@ def dense_decode_mq_paged(q, cache, ptab, cur, feed_mask, self_entries, *,
 
 def flash_prefill(q, k, v, *, causal: bool = True, window: int | None = None,
                   scale: float | None = None, block: int = 256,
-                  interpret: bool | None = None):
+                  interpret: bool | None = None, mesh=None):
     """Full-sequence flash attention for prefill/training forward paths.
 
     q: (B, T, H, dh); k: (B, T, Hkv, dh); v: (B, T, Hv, dv) — Hv may be the
-    latent group count G.  Arbitrary T (tail tiles padded internally)."""
-    return flash_prefill_attention(
-        q, k, v, causal=causal, window=window, scale=scale,
-        block_q=block, block_k=block,
-        interpret=_resolve_interpret(interpret))
+    latent group count G.  Arbitrary T (tail tiles padded internally).
+    On a multi-device ``mesh`` the kernel runs under a full-manual
+    shard_map (Mosaic cannot be partitioned by pjit): rows split over the
+    batch axes, heads and sequence whole on every "model" shard."""
+    def call(q, k, v):
+        return flash_prefill_attention(
+            q, k, v, causal=causal, window=window, scale=scale,
+            block_q=block, block_k=block,
+            interpret=_resolve_interpret(interpret))
+
+    if mesh is None or mesh.size == 1:
+        return call(q, k, v)
+    spec = P(R.kernel_batch_axes(mesh, q.shape[0]), None, None, None)
+    return jax.shard_map(call, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 __all__ = [

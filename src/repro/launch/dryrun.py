@@ -15,6 +15,10 @@ For every cell we
 and record memory / FLOPs / collective-bytes (parsed from the post-SPMD
 HLO) into a resumable JSONL that §Roofline and benchmarks/roofline.py read.
 
+Off the chip path: importing this module sets ``XLA_FLAGS`` for 512
+forced-host CPU devices, so run it as its own CPU process, never beside
+code that drives a TPU.
+
 Usage:
     python -m repro.launch.dryrun --arch qwen3-4b --shape train_4k
     python -m repro.launch.dryrun --all --out experiments/dryrun.jsonl

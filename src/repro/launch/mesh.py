@@ -11,14 +11,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh, passing Auto axis_types only where the jax version
-    has them (0.4.x predates jax.sharding.AxisType; Auto is its default
-    behavior there anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (pjit-style sharding propagation)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -10,6 +10,9 @@ queue depth, comparing dense vs ReCalKV cache footprints.
 with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to try it on
 CPU).  Without ``--mesh`` the engine runs the same code path on a
 degenerate (1, 1) mesh.
+
+Exits non-zero when any request is left unfinished.  The persistent
+compilation cache goes where ``repro.launch.compile_cache`` says.
 """
 
 from __future__ import annotations
@@ -21,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, RECALKV_APPLICABLE, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import mesh_from_spec
 from repro.models import transformer as T
 from repro.serving import Engine, Request, SamplingParams
@@ -126,6 +130,7 @@ def main(argv=None):
                     help="record the host-boundary stage timeline "
                          "(metrics()['profile'])")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     kw = {"smoke": args.smoke}
     if args.recalkv is not None:
@@ -211,7 +216,7 @@ def main(argv=None):
               + (f", {m['pages_parked']} pages parked"
                  if m['pages_parked'] else ""))
     if eng.unfinished["queued"] or eng.unfinished["in_flight"]:
-        print(f"[serve] WARNING unfinished: {eng.unfinished}")
+        raise SystemExit(f"[serve] unfinished requests: {eng.unfinished}")
     return finished
 
 

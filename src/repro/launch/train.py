@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs import ARCHS, get_config
 from repro.data import DataConfig, batch as data_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.optim import AdamWConfig
 from repro.runtime import TrainConfig, make_train_step, train_loop
@@ -42,6 +43,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--mesh", default=None, help="e.g. 16x16 (TPU only)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.smoke:

@@ -41,7 +41,9 @@ def capture_calibration(cfg: ModelConfig, params, batches) -> list[P.CalibStats]
     """Per-self-attention-layer input (post-ln1) second moments."""
     kinds = _unrolled(cfg)
 
-    def hidden_taps(tokens, source=None):
+    # params are an argument, not a closure: jit embeds closed-over arrays
+    # in the program as constants, which at full width is the whole model.
+    def hidden_taps(params, tokens, source=None):
         B, Tn = tokens.shape
         if cfg.encoder_decoder and source is not None:
             source = T.encode(cfg, params, source)
@@ -60,7 +62,7 @@ def capture_calibration(cfg: ModelConfig, params, batches) -> list[P.CalibStats]
     tap_fn = jax.jit(hidden_taps)
     stats: list[P.CalibStats] | None = None
     for batch in batches:
-        taps = tap_fn(batch["tokens"], batch.get("source"))
+        taps = tap_fn(params, batch["tokens"], batch.get("source"))
         new = [P.collect_stats(t) for t in taps]
         stats = new if stats is None else [
             P.merge_stats(a, b) for a, b in zip(stats, new)

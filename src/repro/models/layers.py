@@ -196,12 +196,12 @@ def reconstruct_keys(zk: jax.Array, r_k: jax.Array, num_kv_heads: int,
 def self_attention_dense(p: Params, x: jax.Array, cfg: ModelConfig,
                          positions: jax.Array, window: int | None,
                          theta: float | None = None, causal: bool = True,
-                         use_kernel: bool = True):
+                         use_kernel: bool = True, mesh=None):
     """Returns (y, (k_roped, v)) — the tuple feeds prefill cache writes.
 
     ``use_kernel=False`` forces the einsum path even under
     ``attn_backend="pallas"`` — the training forward needs it (the Pallas
-    kernels carry no autodiff rule)."""
+    kernels carry no autodiff rule).  ``mesh`` as in ``kops.flash_prefill``."""
     B, T, _ = x.shape
     H, Hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.d_head
     q = (x @ p["wq"]).reshape(B, T, H, dh)
@@ -222,7 +222,8 @@ def self_attention_dense(p: Params, x: jax.Array, cfg: ModelConfig,
         # Prefill positions are always 0..T-1, which is exactly the flash
         # kernel's block-position mask.
         o = kops.flash_prefill(q, k, v, causal=causal, window=window,
-                               scale=dh ** -0.5, block=cfg.attn_block)
+                               scale=dh ** -0.5, block=cfg.attn_block,
+                               mesh=mesh)
     else:
         o = chunked_attention(q, k, v, positions, positions, window=window,
                               scale=dh ** -0.5, chunk=cfg.attn_chunk,
@@ -233,10 +234,10 @@ def self_attention_dense(p: Params, x: jax.Array, cfg: ModelConfig,
 def self_attention_latent(p: Params, x: jax.Array, cfg: ModelConfig,
                           positions: jax.Array, window: int | None,
                           theta: float | None = None,
-                          use_kernel: bool = True):
+                          use_kernel: bool = True, mesh=None):
     """Full-sequence ReCalKV attention.  Returns (y, (zk, zv)) — the latents
     are exactly what prefill writes into the ring cache (pre-RoPE).
-    ``use_kernel`` as in :func:`self_attention_dense`."""
+    ``use_kernel`` and ``mesh`` as in :func:`self_attention_dense`."""
     B, T, _ = x.shape
     H, Hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.d_head
     rt = cfg.recalkv
@@ -258,7 +259,8 @@ def self_attention_latent(p: Params, x: jax.Array, cfg: ModelConfig,
         # (B, T, H, r_v) outputs for the fused W~_o — K is reconstructed
         # once here but never cached.
         o_lat = kops.flash_prefill(q, k, zv, causal=True, window=window,
-                                   scale=dh ** -0.5, block=cfg.attn_block)
+                                   scale=dh ** -0.5, block=cfg.attn_block,
+                                   mesh=mesh)
     else:
         o_lat = chunked_attention(q, k, zv, positions, positions,
                                   window=window, scale=dh ** -0.5,

@@ -274,14 +274,16 @@ def block_full(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
     elif cfg.recalkv is not None:
         y, kv = L.self_attention_latent(p["attn"], h, cfg, pos, window,
                                         theta=_theta(cfg, kind),
-                                        use_kernel=want_cache)
+                                        use_kernel=want_cache,
+                                        mesh=ctx.get("mesh"))
         if want_cache:
             self_cache = _prefill_self_cache(
                 cfg, kind, ctx, KC.latent_cache_entry(cfg, kv[0], kv[1]))
     else:
         y, kv = L.self_attention_dense(p["attn"], h, cfg, pos, window,
                                        theta=_theta(cfg, kind), causal=causal,
-                                       use_kernel=want_cache)
+                                       use_kernel=want_cache,
+                                       mesh=ctx.get("mesh"))
         if want_cache:
             self_cache = _prefill_self_cache(cfg, kind, ctx, {"k": kv[0], "v": kv[1]})
     x = x + y
@@ -642,14 +644,18 @@ def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
-            lengths: jax.Array, max_len: int, source: jax.Array | None = None):
-    """Aligned right-padded prefill.  Returns (last_logits (B,V), caches)."""
+            lengths: jax.Array, max_len: int, source: jax.Array | None = None,
+            mesh=None):
+    """Aligned right-padded prefill.  Returns (last_logits (B,V), caches).
+    ``mesh`` (closure capture, as in :func:`decode_step`) runs the pallas
+    prefill kernel under shard_map on a multi-device mesh."""
     B, T = tokens.shape
     if cfg.encoder_decoder and source is not None:
         source = encode(cfg, params, source)
     ctx = {
         "positions": jnp.broadcast_to(jnp.arange(T), (B, T)),
         "lengths": lengths, "source": source, "max_len": max_len,
+        "mesh": mesh,
     }
     x = embed_tokens(cfg, params, tokens)
     x, caches, _ = run_stack(cfg, params, x, ctx, caches={})

@@ -94,15 +94,6 @@ __all__ = ["Engine", "Request", "SamplingParams", "DraftSpec"]
 STAGE_FREE = np.iinfo(np.int32).max
 
 
-def _array_ready(x) -> bool:
-    """True when a device array's computation has already completed (the
-    dispatch-side probe behind the ``window_overlap`` metric)."""
-    try:
-        return bool(x.is_ready())
-    except AttributeError:          # older jax: no probe, call it ready
-        return True
-
-
 def _merge_slot(pool_cache, new_cache, slots: jax.Array, rows=None):
     """Copy ``new_cache`` batch rows into ``pool_cache`` at ``slots``.
     Without ``rows`` the leading ``len(slots)`` source rows are taken
@@ -540,7 +531,7 @@ class Engine:
 
         def _prefill_fn(p, t, l):
             self.trace_counts["prefill"] += 1
-            return T.prefill(cfg, p, t, l, max_len=max_len,
+            return T.prefill(cfg, p, t, l, max_len=max_len, mesh=self.mesh,
                              source=None if source is None
                              else source[: t.shape[0]])
 
@@ -553,6 +544,7 @@ class Engine:
             def _draft_prefill_fn(p, t, l):
                 self.trace_counts["draft_prefill"] += 1
                 return T.prefill(dcfg, p, t, l, max_len=max_len,
+                                 mesh=self.mesh,
                                  source=None if source is None
                                  else source[: t.shape[0]])
 
@@ -2266,7 +2258,7 @@ class Engine:
             return False
         occ, qd = self.scheduler.occupancy, self.scheduler.queue_depth
         prior = self._inflight[-1] if self._inflight else None
-        overlapped = prior is not None and not _array_ready(prior.status)
+        overlapped = prior is not None and not prior.status.is_ready()
         acc = prop = sw_seq = sw_slot = None
         if self.draft_cache is not None:
             (self.cache, self.draft_cache, st2, toks, emits, acc,
@@ -2645,15 +2637,19 @@ class Engine:
         timeout the engine warns and leaves the backlog inspectable via
         ``engine.unfinished`` (callers distinguish drain from timeout).
         A stuck load (a request that can never admit) exits via the idle
-        guard instead of spinning to max_steps."""
+        guard instead of spinning to max_steps.  A wave still being
+        prepared on the admission worker is progress: a cold prefill
+        compile outlasts the guard's bounded waits."""
         idle = 0
         while self.scheduler.has_work or self._inflight:
             if self.windows >= max_steps:
                 break
             before = (self.windows, self.host_syncs, self._dispatch_index)
             self.step()
-            made_progress = (self.windows, self.host_syncs,
-                             self._dispatch_index) != before
+            made_progress = ((self.windows, self.host_syncs,
+                              self._dispatch_index) != before
+                             or (self._admission is not None
+                                 and self._admission.busy))
             idle = 0 if made_progress else idle + 1
             if idle > self.B + 2:
                 break

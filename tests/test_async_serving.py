@@ -433,6 +433,29 @@ class TestLifecycle:
         assert emitted == eng.metrics()["tokens"]
         eng.close()
 
+    def test_run_outlasts_a_slow_admission_prefill(self):
+        """A wave whose prefill takes longer than the idle guard's
+        bounded waits (a cold compile of a full-size model) is progress:
+        run() must drain, not stop with every request still queued."""
+        import time
+        cfg, params = _model("latent")
+        eng = Engine(cfg, params, max_slots=1, max_len=40, overlap=True)
+        prepare = eng._admission._prepare
+
+        def slow_prepare(reqs):
+            time.sleep(5.0)        # > (max_slots + 3) waits of 1 s each
+            return prepare(reqs)
+
+        eng._admission._prepare = slow_prepare
+        for i, pr in enumerate(_prompts(cfg, n=2)):
+            eng.submit(Request(uid=i, prompt=pr.copy(), max_new_tokens=3))
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", RuntimeWarning)
+            done = eng.run()
+        eng.close()
+        assert eng.unfinished == {"queued": 0, "in_flight": 0}
+        assert sorted(len(r.out_tokens) for r in done) == [3, 3]
+
     def test_on_token_streaming_order(self):
         """Request.on_token fires once per token, in stream order, on the
         backlog worker — the callback view equals out_tokens."""

@@ -26,7 +26,7 @@ KEY = jax.ShapeDtypeStruct((2,), jnp.uint32)
 
 def fake_mesh(shape, axes):
     """AbstractMesh stands in for a device mesh in pure spec computations."""
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 @pytest.mark.parametrize("arch", ARCHS)
