@@ -24,7 +24,8 @@ swaps — ``occupancy_device_mean`` (mean active slots per fused-scan
 iteration), ``slot_swaps``, client-observed inter-token latency
 (``itl_p50_ms``/``itl_p95_ms``) and the host-boundary stage shares
 (``profile_shares``) are the recorded trajectory; ``--profile PATH``
-additionally dumps the per-event boundary timeline as JSON.
+additionally dumps the continuous row's stage seconds and shares as JSON
+(the per-event timeline is a profiler trace of the engine's spans).
 
 Mesh rows: the latent load is re-run over engine mesh shapes (``1x1``
 and ``2x4``) for BOTH backends — the pallas rows exercise the shard_map
@@ -90,7 +91,7 @@ def bench_engine(arch: str, variant: str, backend: str, *, slots: int,
                  continuous: bool = False,
                  admission_thread: bool | None = None,
                  policy: str | None = None, lazy_pages: bool = False,
-                 profile: bool = False, new_tokens_list=None,
+                 new_tokens_list=None,
                  stamp_tokens: bool = False,
                  profile_out: dict | None = None) -> dict:
     kw, extra = VARIANTS[variant]
@@ -105,7 +106,7 @@ def bench_engine(arch: str, variant: str, backend: str, *, slots: int,
                  n_pages=n_pages, overlap=overlap, aot=aot,
                  pipeline_depth=pipeline_depth, continuous=continuous,
                  admission_thread=admission_thread, policy=policy,
-                 lazy_pages=lazy_pages, profile=profile)
+                 lazy_pages=lazy_pages)
     if prompts is None:
         g = np.random.default_rng(1)
         prompts = [g.integers(0, cfg.vocab_size,
@@ -212,10 +213,8 @@ def bench_engine(arch: str, variant: str, backend: str, *, slots: int,
     if workload:
         row["workload"] = workload
     if profile_out is not None:
-        # the bounded per-event timeline (engine profile=True) plus the
-        # aggregate shares — dumped by --profile as a standalone JSON
+        # the aggregate stage seconds and shares, dumped by --profile
         profile_out["profile"] = m["profile"]
-        profile_out["events"] = list(eng._prof_events)
     return row
 
 
@@ -545,7 +544,7 @@ def bench_continuous_rows(arch: str, *, slots: int, max_len: int,
     t0 = time.time()
     cont_row = bench_engine(arch, "latent", "einsum", overlap=True,
                             aot=True, pipeline_depth=3, continuous=True,
-                            profile=True, stamp_tokens=True,
+                            stamp_tokens=True,
                             profile_out=profile_out, **common)
     cont_row["bench_seconds"] = round(time.time() - t0, 1)
     if sync_row["tokens_per_s"] > 0:
@@ -743,9 +742,8 @@ def main(argv=None):
                          "(run in a forced-host subprocess) and exit")
     ap.add_argument("--out", default=DEFAULT_OUT)
     ap.add_argument("--profile", default=None, metavar="PATH",
-                    help="dump the continuous-row host-boundary timeline "
-                         "(per-event stage/t/dur + aggregate shares) as "
-                         "JSON to PATH")
+                    help="dump the continuous row's host-boundary stage "
+                         "seconds and shares as JSON to PATH")
     args = ap.parse_args(argv)
     if args.one_mesh_row:
         row = bench_engine(args.arch, "latent", args.backend,
@@ -766,8 +764,8 @@ def main(argv=None):
     if args.profile:
         with open(args.profile, "w") as f:
             json.dump(profile_out, f, indent=1)
-        print(f"host-boundary timeline ({len(profile_out['events'])} "
-              f"events) written to {os.path.abspath(args.profile)}")
+        print(f"host-boundary stage profile written to "
+              f"{os.path.abspath(args.profile)}")
 
 
 if __name__ == "__main__":

@@ -119,8 +119,8 @@ def serve(artifact: CompressionArtifact | str, *, max_slots: int,
     instance), ``lazy_pages`` allocates cache pages as generation
     reaches them (preempting a policy-chosen victim on pool
     exhaustion), ``staging_depth`` bounds the admission worker's
-    look-ahead — plus ``adaptive_spec``, ``pin_prefixes`` and
-    ``profile``; token streams are invariant to all of these) pass
+    look-ahead — plus ``adaptive_spec`` and ``pin_prefixes``; token
+    streams are invariant to all of these) pass
     through to the Engine."""
     from repro.serving.engine import Engine  # local: engine imports api too
 

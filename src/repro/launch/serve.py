@@ -126,9 +126,6 @@ def main(argv=None):
                     help="degrade cold-draft slots to plain decode at "
                          "window boundaries (needs --spec-depth > 0; "
                          "streams are invariant)")
-    ap.add_argument("--profile", action="store_true",
-                    help="record the host-boundary stage timeline "
-                         "(metrics()['profile'])")
     args = ap.parse_args(argv)
     use_compile_cache()
 
@@ -162,7 +159,7 @@ def main(argv=None):
                  pin_prefixes=args.pin_prefixes,
                  policy=args.policy, lazy_pages=args.lazy_pages,
                  staging_depth=args.staging_depth,
-                 adaptive_spec=args.adaptive_spec, profile=args.profile)
+                 adaptive_spec=args.adaptive_spec)
     spec = (f", spec_depth={args.spec_depth} ({eng.metrics()['draft']})"
             if args.spec_depth else "")
     layout = ("" if args.cache_layout == "ring" else

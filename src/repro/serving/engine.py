@@ -58,6 +58,7 @@ compression the same HBM holds 2x the slots (the paper's serving win).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import threading
@@ -92,6 +93,24 @@ __all__ = ["Engine", "Request", "SamplingParams", "DraftSpec"]
 # picks argmin(seq), so the max int32 sorts every real (monotonically
 # assigned) staging sequence number ahead of every free row.
 STAGE_FREE = np.iinfo(np.int32).max
+
+# Stage spans whose wall-clock also accrues to ``metrics()["profile"]``,
+# under these keys; the others (engine.step, engine.finish, engine.merge,
+# engine.flush) nest in or around them and appear on the profiler only.
+PROFILE_KEYS = {"engine.dispatch": "dispatch", "engine.harvest": "harvest",
+                "engine.bookkeep": "bookkeep",
+                "engine.admission_stage": "admission_stage",
+                "backlog.drain": "backlog_drain",
+                "admission.prepare": "admission_worker"}
+
+# slot refills kept for metrics() (see Engine._note_refills)
+REFILLS_KEPT = 4096
+
+
+def _ids(xs) -> str:
+    """A span argument listing ints.  Space-joined: TraceMe's argument
+    encoding splits on commas."""
+    return " ".join(str(x) for x in xs)
 
 
 def _merge_slot(pool_cache, new_cache, slots: jax.Array, rows=None):
@@ -175,7 +194,12 @@ class Engine:
     worker thread (default: on whenever overlap is); ``adaptive_spec``
     degrades cold-draft slots to plain decode at window boundaries;
     ``pin_prefixes`` pins the K hottest registered prefix pages against
-    pool recycling; ``profile`` records a host-boundary stage timeline.
+    pool recycling.
+
+    The host's stages (``engine.*`` on the calling thread, ``admission.*``
+    and ``backlog.*`` on the workers) are always spans on the profiler's
+    clock (``jax.profiler.TraceAnnotation``); the main ones also sum into
+    ``metrics()["profile"]``.
     """
 
     # adaptive speculation: degrade a slot once it has proposed at least
@@ -201,7 +225,6 @@ class Engine:
                  admission_thread: bool | None = None,
                  pin_prefixes: int = 0,
                  adaptive_spec: bool = False,
-                 profile: bool = False,
                  policy: str | AdmissionPolicy | None = None,
                  lazy_pages: bool = False,
                  staging_depth: int | None = None):
@@ -469,6 +492,8 @@ class Engine:
         self._ttft_n = 0
         self.preemptions = 0         # slots evicted by lazy reservation
         self.prefill_calls = 0       # admission wave-prefill dispatches
+        self.prefill_tokens = 0      # ... their real prompt tokens
+        self.prefill_positions = 0   # ... their bucketed rows x length
         self.prefill_calls_saved = 0  # admissions served without a prefill
         self._preempted: deque[PreemptedRecord] = deque()
 
@@ -481,7 +506,6 @@ class Engine:
         self.admission_thread = (bool(overlap) if admission_thread is None
                                  else bool(admission_thread))
         self.pin_prefixes = pin_prefixes
-        self.profile = bool(profile)
         self._inflight: deque[InflightWindow] = deque()
         self._st_dev: dict | None = None     # device-resident carry
         self._dispatch_index = 0             # windows dispatched so far
@@ -511,18 +535,19 @@ class Engine:
         self._spec_acc = np.zeros(max_slots, np.int64)
         self._spec_prop = np.zeros(max_slots, np.int64)
         self._prefix_hits: dict[int, int] = {}   # page -> registry hits
-        # host-boundary profiler: per-stage wall-clock sums (always on —
-        # the counters are cheap); profile=True additionally records a
-        # bounded event timeline for serving_bench --profile.
-        self._prof = {k: 0.0 for k in
-                      ("dispatch", "harvest", "bookkeep", "admission_stage",
-                       "backlog_drain")}
-        self._prof_events: list[dict] = []
-        self._prof_t0 = time.perf_counter()
         self._admission: AdmissionWorker | None = None
         if self.overlap and self.admission_thread:
             self._admission = AdmissionWorker(self._take_staged_locked,
                                               self._prepare_wave)
+        # per-stage wall-clock sums of the spans in PROFILE_KEYS (the
+        # worker's stage only when there is a worker)
+        self._prof = {k: 0.0 for k in PROFILE_KEYS.values()
+                      if k != "admission_worker" or self._admission is not None}
+        # slot -> when its last engine.finish ended; (merge start, seconds
+        # the slot stood empty) of each refill that followed one
+        self._freed_at: dict[int, float] = {}
+        self._refills: deque[tuple[float, float]] = deque(
+            maxlen=REFILLS_KEPT)
 
         # trace-count hooks: the counters bump inside the traced python
         # functions, so they advance exactly once per (re)trace — the AOT
@@ -1024,7 +1049,6 @@ class Engine:
                       admission_thread: bool | None = None,
                       pin_prefixes: int = 0,
                       adaptive_spec: bool = False,
-                      profile: bool = False,
                       policy: str | AdmissionPolicy | None = None,
                       lazy_pages: bool = False,
                       staging_depth: int | None = None) -> "Engine":
@@ -1044,7 +1068,7 @@ class Engine:
                    pipeline_depth=pipeline_depth, continuous=continuous,
                    admission_thread=admission_thread,
                    pin_prefixes=pin_prefixes, adaptive_spec=adaptive_spec,
-                   profile=profile, policy=policy, lazy_pages=lazy_pages,
+                   policy=policy, lazy_pages=lazy_pages,
                    staging_depth=staging_depth)
 
     # -- back-compat conveniences -------------------------------------------
@@ -1093,10 +1117,13 @@ class Engine:
         with self._sched_lock:
             return self.scheduler.take_staged(max_n)
 
-    def _count_prefill(self):
-        """One admission wave-prefill dispatch (any thread)."""
+    def _count_prefill(self, toks: np.ndarray, lens: np.ndarray):
+        """One admission wave-prefill dispatch (any thread): its real
+        prompt tokens and its bucketed (rows, length) positions."""
         with self._mlock:
             self.prefill_calls += 1
+            self.prefill_tokens += int(lens.sum())
+            self.prefill_positions += toks.size
 
     def _record_token(self, req: Request, tok: int):
         """Credit one emitted token to a request: append, stamp ttft on
@@ -1412,7 +1439,7 @@ class Engine:
         all-skip wave."""
         logits = new_cache = None
         if toks is not None:
-            self._count_prefill()
+            self._count_prefill(toks, lens)
             tj, lj = self._prefill_args(toks, lens)
             logits, new_cache = self._prefill(self.params, tj, lj)
         if self._pages is None:
@@ -1811,7 +1838,7 @@ class Engine:
         toks = np.zeros((1, _bucket(cur, self.max_len)), np.int32)
         toks[0, :cur] = fed
         lens = np.array([cur], np.int32)
-        self._count_prefill()
+        self._count_prefill(toks, lens)
         tj, lj = self._prefill_args(toks, lens)
         _, new_cache = self._prefill(self.params, tj, lj)
         self.cache = _merge_slot_paged(
@@ -1884,18 +1911,22 @@ class Engine:
                 eos[i] = r.eos_id
             full[i] = rows[i] >= 0 and first_lens[i] == len(r.prompt)
         ks = first = new_cache = draft_new = None
+        toks = np.zeros((0, 0), np.int32)
         if w:
             toks, lens = self._bucket_prompts(reqs, first_lens, rows, w)
-            self._count_prefill()
-            tj, lj = self._prefill_args(toks, lens)
-            logits, new_cache = self._prefill(self.params, tj, lj)
-            if self.draft_cache is not None:
-                _, draft_new = self._draft_prefill(self.draft_params,
-                                                   tj, lj)
-            preqs = [r for r, ri in zip(reqs, rows) if ri >= 0]
-            pflens = [fl for fl, ri in zip(first_lens, rows) if ri >= 0]
-            _, _, _, _, ks, first = self._admit_sample_first(
-                preqs, pflens, logits)
+        with self._stage("admission.prepare", uids=_ids(r.uid for r in reqs),
+                         rows=toks.shape[0], length=toks.shape[1]):
+            if w:
+                self._count_prefill(toks, lens)
+                tj, lj = self._prefill_args(toks, lens)
+                logits, new_cache = self._prefill(self.params, tj, lj)
+                if self.draft_cache is not None:
+                    _, draft_new = self._draft_prefill(self.draft_params,
+                                                       tj, lj)
+                preqs = [r for r, ri in zip(reqs, rows) if ri >= 0]
+                pflens = [fl for fl, ri in zip(first_lens, rows) if ri >= 0]
+                _, _, _, _, ks, first = self._admit_sample_first(
+                    preqs, pflens, logits)
         return StagedWave(reqs=list(reqs), first_lens=first_lens,
                           specs=specs, keys0=keys0, eos=eos, full=full,
                           ks=ks, first=first, new_cache=new_cache,
@@ -1954,7 +1985,12 @@ class Engine:
             with self._sched_lock:
                 placed = self.scheduler.place_wave(todo[:n])
             idx = list(range(wv.merged, wv.merged + n))
-            self._merge_wave_rows(wv, placed, idx)
+            slots = [s for s, _ in placed]
+            with self._stage("engine.merge",
+                             uids=_ids(r.uid for _, r in placed),
+                             slots=_ids(slots)) as t_merge:
+                self._note_refills(slots, t_merge)
+                self._merge_wave_rows(wv, placed, idx)
             wv.merged += n
             if wv.merged < len(wv.reqs):
                 return
@@ -2055,8 +2091,8 @@ class Engine:
         entries = [(r, prow(i)) for i, (_, r) in zip(idx, placed)
                    if wv.full[i]]
         if entries:
-            self._backlog.put(self._timed(
-                self._admit_item(wv.first, entries), "backlog_drain"))
+            self._backlog.put(self._drained(
+                self._admit_item(wv.first, entries), self._dispatch_index))
 
     def _stage_bookkeep(self, r: Request, sp, first_len: int, eos_id):
         """Host-known carry ROW for a staged request — everything
@@ -2205,8 +2241,8 @@ class Engine:
             # admission); it must reach the stream before any window item
             # carrying this request's later tokens — backlog FIFO does it
             self._admit_tokens += 1
-            self._backlog.put(self._timed(
-                self._admit_item(wv.first, [(r, ri)]), "backlog_drain"))
+            self._backlog.put(self._drained(
+                self._admit_item(wv.first, [(r, ri)]), self._dispatch_index))
 
     def _admit_item(self, first, entries):
         def item():
@@ -2312,10 +2348,13 @@ class Engine:
         epoch-eligible mirror slots, retire finished requests, and hand
         token work to the backlog."""
         w = self._inflight.popleft()
-        t0 = time.perf_counter()
-        status = np.asarray(w.status)
-        t1 = time.perf_counter()
-        self._prof_add("harvest", t0, t1 - t0)
+        with self._stage("engine.harvest", window=w.index):
+            status = np.asarray(w.status)
+        with self._stage("engine.bookkeep"):
+            self._bookkeep(w, status)
+
+    def _bookkeep(self, w: InflightWindow, status: np.ndarray):
+        """The host half of a harvest, on the trailing window's status."""
         self.host_syncs += 1
         self.windows += 1
         self._occupancy_sum += w.occ
@@ -2353,7 +2392,10 @@ class Engine:
                 swaps.append((k, int(sw_slot[k]), ent.req))
         item = self._window_item(w, base, swaps)
         for k, s, q, ent in installs:
-            self._install_entry(w, s, q, ent)
+            with self._stage("engine.merge", uids=_ids([ent.req.uid]),
+                             slots=_ids([s])) as t_merge:
+                self._note_refills([s], t_merge)
+                self._install_entry(w, s, q, ent)
         ok = self._slot_epoch <= w.index
         if accs is not None:
             self._adaptive_update(ok, act, accs, props,
@@ -2362,13 +2404,20 @@ class Engine:
         self._st["cur"][ok] = cur[ok]
         bok = ok & (self._buf_epoch <= w.index)
         self._st["bpos"][bok] = bpos[bok]
-        self._backlog.put(self._timed(item, "backlog_drain"))
-        for slot, r in enumerate(w.slot_reqs):
-            if (r is not None and ok[slot] and not act[slot]
-                    and self.scheduler.slot_req[slot] is r):
-                self._finish(slot)
+        self._backlog.put(self._drained(item, w.index))
+        done = [slot for slot, r in enumerate(w.slot_reqs)
+                if (r is not None and ok[slot] and not act[slot]
+                    and self.scheduler.slot_req[slot] is r)]
+        if done:
+            with self._stage("engine.finish",
+                             uids=_ids(w.slot_reqs[s].uid for s in done),
+                             slots=_ids(done)):
+                for slot in done:
+                    self._finish(slot)
+            t_free = time.perf_counter()
+            for slot in done:
+                self._freed_at[slot] = t_free
         self.slot_swaps += len(installs)
-        self._prof_add("bookkeep", t1, time.perf_counter() - t1)
 
     def _install_entry(self, w: InflightWindow, s: int, q: int,
                        ent: StagedEntry):
@@ -2477,16 +2526,13 @@ class Engine:
         if len(self._inflight) >= self.pipeline_depth:
             self._harvest_trailing()
         self._ensure_dev_state()
-        t1 = time.perf_counter()
-        self._readmit_preempted()
-        self._admit_overlap()
-        self._refill_async()
-        self._lazy_topup()
-        t2 = time.perf_counter()
-        self._prof_add("admission_stage", t1, t2 - t1)
-        dispatched = self._dispatch_window()
-        t3 = time.perf_counter()
-        self._prof_add("dispatch", t2, t3 - t2)
+        with self._stage("engine.admission_stage"):
+            self._readmit_preempted()
+            self._admit_overlap()
+            self._refill_async()
+            self._lazy_topup()
+        with self._stage("engine.dispatch", window=self._dispatch_index):
+            dispatched = self._dispatch_window()
         if not dispatched:
             if self._inflight:
                 # nothing to decode by the host's (possibly stale) view:
@@ -2502,33 +2548,49 @@ class Engine:
                 self._admission.wait(timeout=1.0)
         self._run_seconds += time.perf_counter() - t0
 
-    def _prof_add(self, stage: str, t0: float, dur: float):
-        with self._mlock:
-            self._prof[stage] += dur
-            if self.profile and len(self._prof_events) < 100_000:
-                self._prof_events.append(
-                    {"stage": stage, "t": t0 - self._prof_t0, "dur": dur})
-
-    def _timed(self, fn, stage: str):
-        """Wrap a backlog work item so its wall-clock accrues to the
-        named profiler stage (on whichever thread runs it)."""
-        def run():
+    @contextlib.contextmanager
+    def _stage(self, name: str, **args):
+        """One host stage: a profiler span ``name`` (with ``args``) on
+        the calling thread, yielding its start on ``time.perf_counter``;
+        a stage in PROFILE_KEYS also adds its wall-clock to
+        ``metrics()["profile"]``.  Never per token."""
+        with jax.profiler.TraceAnnotation(name, **args):
             t0 = time.perf_counter()
             try:
-                fn()
+                yield t0
             finally:
-                self._prof_add(stage, t0, time.perf_counter() - t0)
+                key = PROFILE_KEYS.get(name)
+                if key in self._prof:
+                    dt = time.perf_counter() - t0
+                    with self._mlock:
+                        self._prof[key] += dt
+
+    def _drained(self, fn, window: int):
+        """Wrap a backlog work item in its ``backlog.drain`` stage."""
+        def run():
+            with self._stage("backlog.drain", window=window):
+                fn()
         return run
+
+    def _note_refills(self, slots, t: float):
+        """Slots filled at ``t``: each one an engine.finish emptied is a
+        refill, kept as (t, seconds the slot stood empty)."""
+        with self._mlock:
+            for s in slots:
+                freed = self._freed_at.pop(s, None)
+                if freed is not None:
+                    self._refills.append((t, t - freed))
 
     def flush(self):
         """Drain the pipeline: harvest every in-flight window and block
         until the backlog worker has processed all queued token work.
         No-op on a sync engine."""
         t0 = time.perf_counter()
-        while self._inflight:
-            self._harvest_trailing()
-        if self._backlog is not None and self._backlog.started:
-            self._backlog.flush()
+        with self._stage("engine.flush"):
+            while self._inflight:
+                self._harvest_trailing()
+            if self._backlog is not None and self._backlog.started:
+                self._backlog.flush()
         self._run_seconds += time.perf_counter() - t0
 
     def close(self):
@@ -2559,8 +2621,15 @@ class Engine:
         meaningful ``tokens_per_s`` out of :meth:`metrics`.  Idle no-op
         calls (nothing active, nothing admitted) accrue nothing: an
         event loop polling an empty engine must not dilute the rate."""
-        if self.overlap:
-            return self._step_async()
+        with self._stage("engine.step", window=(self._dispatch_index
+                                                if self.overlap
+                                                else self.windows)):
+            if self.overlap:
+                self._step_async()
+            else:
+                self._step_sync()
+
+    def _step_sync(self):
         t0 = time.perf_counter()
         self._readmit_preempted()
         self._admit()
@@ -2683,7 +2752,12 @@ class Engine:
         dispatched before the prior one had finished on device — the
         direct measure of how often the double buffer actually hid the
         host; ``windows_idle`` counts harvested windows that emitted
-        nothing (pipeline bubbles after a drain)."""
+        nothing (pipeline bubbles after a drain).  ``prefill_tokens`` /
+        ``prefill_positions`` count the real prompt tokens and the
+        bucketed positions of the prefills dispatched; ``slot_refills``
+        lists the latest overlapped refills as (``time.perf_counter`` at
+        the start of the ``engine.merge`` that filled the slot, seconds
+        since the ``engine.finish`` that emptied it)."""
         with self._mlock:
             tokens = self.tokens_emitted + self._admit_tokens
             windows_idle = self.windows_idle
@@ -2692,13 +2766,13 @@ class Engine:
             draft_accepted = self.draft_accepted
             preemptions = self.preemptions
             prefill_calls = self.prefill_calls
+            prefill_tokens = self.prefill_tokens
+            prefill_positions = self.prefill_positions
             prefill_calls_saved = self.prefill_calls_saved
+            prof = dict(self._prof)
+            refills = [list(r) for r in self._refills]
         w = max(self.windows, 1)
         pool = self._pages
-        with self._mlock:
-            prof = dict(self._prof)
-        if self._admission is not None:
-            prof["admission_worker"] = self._admission.prepare_seconds
         ptotal = sum(prof.values())
         profile = {"seconds": prof,
                    "shares": {k: (v / ptotal if ptotal else 0.0)
@@ -2761,7 +2835,10 @@ class Engine:
             "staging_depth": self.staging_depth,
             "preemptions": preemptions,
             "prefill_calls": prefill_calls,
+            "prefill_tokens": prefill_tokens,
+            "prefill_positions": prefill_positions,
             "prefill_calls_saved": prefill_calls_saved,
+            "slot_refills": refills,
             "profile": profile,
             "ttft_s": ttft,
             "prefix_resurrections": (0 if pool is None

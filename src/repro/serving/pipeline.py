@@ -251,7 +251,6 @@ class AdmissionWorker:
         self._stop = False
         self._thread: threading.Thread | None = None
         self.waves_prepared = 0
-        self.prepare_seconds = 0.0     # worker-thread time (profiler)
 
     @property
     def started(self) -> bool:
@@ -279,7 +278,6 @@ class AdmissionWorker:
             self._cv.notify_all()
 
     def _run(self):
-        import time
         while True:
             with self._cv:
                 while not self._stop and self._capacity <= 0:
@@ -300,13 +298,10 @@ class AdmissionWorker:
                 with self._cv:
                     self._busy = True
                     self._capacity -= len(reqs)
-                t0 = time.perf_counter()
                 wave = self._prepare(reqs)
-                dt = time.perf_counter() - t0
                 with self._cv:
                     self._out.append(wave)
                     self.waves_prepared += 1
-                    self.prepare_seconds += dt
                     self._busy = False
                     self._cv.notify_all()
             except BaseException as e:  # noqa: BLE001 — reraised on main
