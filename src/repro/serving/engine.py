@@ -106,6 +106,10 @@ PROFILE_KEYS = {"engine.dispatch": "dispatch", "engine.harvest": "harvest",
 # slot refills kept for metrics() (see Engine._note_refills)
 REFILLS_KEPT = 4096
 
+# carry leaves an admission merge picks on device from the sampled first
+# token; every other leaf's row is known to the host
+DEVICE_ROWS = ("tok", "act", "keys")
+
 
 def _ids(xs) -> str:
     """A span argument listing ints.  Space-joined: TraceMe's argument
@@ -113,25 +117,65 @@ def _ids(xs) -> str:
     return " ".join(str(x) for x in xs)
 
 
-def _merge_slot(pool_cache, new_cache, slots: jax.Array, rows=None):
-    """Copy ``new_cache`` batch rows into ``pool_cache`` at ``slots``.
-    Without ``rows`` the leading ``len(slots)`` source rows are taken
-    (the prefill wave may be padded past that for shape bucketing — the
-    pad rows are dropped here); with ``rows`` (same length as ``slots``)
-    an arbitrary subset of wave rows merges, which is how a staged wave
-    larger than the free slots merges across several boundaries.
+def _merge_rows(pool_cache, new_cache, slots: jax.Array, rows: jax.Array):
+    """Copy ``new_cache`` batch row ``rows[i]`` into ``pool_cache`` slot
+    ``slots[i]``, one row at a time; an out-of-range (pad) slot drops.
+    Traced inside the admission merge: with the pool donated every row
+    lands in place, and no gathered copy of the rows is materialized (a
+    batched scatter holds one as large as the rows, a temporary the
+    allocator can make the host wait for).
 
     Batch is dim 0 for prefix/suffix caches but dim 1 under the scanned
     "blocks" subtree (leading dim = pattern periods)."""
-    n = slots.shape[0]
-    def one(path, pool, new):
-        key0 = getattr(path[0], "key", None)
-        if key0 == "blocks":
-            src = new[:, rows] if rows is not None else new[:, :n]
-            return pool.at[:, slots].set(src.astype(pool.dtype))
-        src = new[rows] if rows is not None else new[:n]
-        return pool.at[slots].set(src.astype(pool.dtype))
-    return jax.tree_util.tree_map_with_path(one, pool_cache, new_cache)
+    def row(i, pool):
+        s = jax.lax.dynamic_slice_in_dim(slots, i, 1)
+        r = jax.lax.dynamic_slice_in_dim(rows, i, 1)
+
+        def one(path, dst, src):
+            if getattr(path[0], "key", None) == "blocks":
+                return dst.at[:, s].set(src[:, r].astype(dst.dtype),
+                                        mode="drop")
+            return dst.at[s].set(src[r].astype(dst.dtype), mode="drop")
+        return jax.tree_util.tree_map_with_path(one, pool, new_cache)
+    return jax.lax.fori_loop(0, slots.shape[0], row, pool_cache)
+
+
+def _admission_merge(cache, dcache, carry, new, dnew, first, ks, ix):
+    """Everything one admission merge puts on the device, as one program:
+    prefill rows ``ix["rows"]`` of ``new`` into ``cache`` slots
+    ``ix["slots"]``, the layer draft's rows (``ix["drows"]`` into
+    ``ix["dslots"]``) into ``dcache``, and every carry leaf's row into
+    ``carry``: the host-known ones from ``ix["host"]``, tok/act/keys
+    picked from the first-token futures (``first``, ``ks``) by
+    ``ix["full"]``, ``ix["eos"]``, ``ix["left"]`` and ``ix["keys0"]``, as
+    the window's own first-token checks would.  A part left None is not
+    merged; ``first`` is None for a wave that prefilled nothing (every
+    row starts from its base key, feeding from the ingest buffer).  Row
+    vectors are padded with an out-of-range slot, which drops."""
+    if cache is not None:
+        cache = _merge_rows(cache, new, ix["slots"], ix["rows"])
+    if dcache is not None:
+        dcache = _merge_rows(dcache, dnew, ix["dslots"], ix["drows"])
+    if carry is not None:
+        rows = dict(ix["host"])
+        full = ix["full"]
+        if first is None:
+            rows["tok"] = jnp.zeros(full.shape, jnp.int32)
+            rows["act"] = jnp.ones(full.shape, bool)
+            rows["keys"] = ix["keys0"]
+        else:
+            f = first[ix["rows"]]
+            rows["tok"] = jnp.where(full, f, 0)
+            # a full-prompt row can die at its very first token (eos, or
+            # an exhausted budget) — the checks the window applies
+            rows["act"] = jnp.where(full, (f != ix["eos"]) & (ix["left"] > 0),
+                                    True)
+            rows["keys"] = jnp.where(full[:, None], ks[ix["rows"]][:, 0],
+                                     ix["keys0"])
+        carry = {k: v.at[ix["slots"]].set(rows[k].astype(v.dtype),
+                                          mode="drop")
+                 for k, v in carry.items()}
+    return cache, dcache, carry
 
 
 def _merge_slot_paged(pool_cache, new_cache, rows: jax.Array,
@@ -185,9 +229,10 @@ class Engine:
     window's packed status, token handling on a backlog worker thread,
     and admission prefill dispatched concurrently with in-flight decode.
     Token streams are invariant to ``overlap`` (the async↔sync parity
-    contract).  ``aot`` lowers + compiles the fused window and every
-    reachable power-of-two (wave, prompt-len) prefill bucket at
-    construction, so the first request pays load time, not trace time.
+    contract).  ``aot`` lowers + compiles the fused window, every
+    reachable power-of-two (wave, prompt-len) prefill bucket and the
+    admission merges at construction, so the first request pays load
+    time, not trace time.
     ``pipeline_depth`` generalizes the double buffer to N windows in
     flight; ``continuous`` adds the device-side staging queue + in-scan
     slot swap; ``admission_thread`` moves wave prefill staging onto a
@@ -552,7 +597,8 @@ class Engine:
         # trace-count hooks: the counters bump inside the traced python
         # functions, so they advance exactly once per (re)trace — the AOT
         # smoke check asserts they stay flat while serving.
-        self.trace_counts = {"window": 0, "prefill": 0, "draft_prefill": 0}
+        self.trace_counts = {"window": 0, "prefill": 0, "draft_prefill": 0,
+                             "merge": 0}
 
         def _prefill_fn(p, t, l):
             self.trace_counts["prefill"] += 1
@@ -651,16 +697,22 @@ class Engine:
             self._stage_dev = jax.device_put(stage_tpl, self._stage_sh)
         else:
             self._carry_sh = in_sh[-1]
+        # one program per admission merge (_admission_merge), per target
+        # and set of parts merged (see _merge_jit); it donates what it
+        # merges into, on the window's rule
+        self._merge_donate = () if jax.default_backend() == "cpu" else (0, 1, 2)
+        self._merge_jits: dict[tuple, Any] = {}
+        self._merge_exec: dict[tuple, Any] = {}
         if self.aot:
             self._aot_compile()
 
     # -- AOT warmup ----------------------------------------------------------
 
     def _aot_compile(self):
-        """Lower + compile the window and every reachable prefill bucket
-        now, so serving never traces: the (wave, prompt-len) shapes
-        ``_bucket`` can produce form a small closed set, and the window's
-        shapes are fixed at construction."""
+        """Lower + compile the window, every reachable prefill bucket and
+        the admission merges now, so serving never traces: the (wave,
+        prompt-len) shapes ``_bucket`` can produce form a small closed
+        set, and the window's shapes are fixed at construction."""
         st = {k: jax.device_put(v, self._carry_sh[k])
               for k, v in self._st.items()}
         if self.draft_cache is not None:
@@ -694,6 +746,66 @@ class Engine:
         if self.draft_cache is not None:
             self._draft_prefill = self._make_prefill_dispatch(
                 self._draft_prefill_jit, self._draft_prefill_exec)
+        self._aot_merges(waves, plens[0])
+
+    def _aot_merges(self, waves, plen: int):
+        """Compile the admission merges this engine's admission paths
+        reach: per prefill wave bucket W, the row buckets a wave of W rows
+        merges in (one row at a time into the continuous stage), plus a
+        prefill-free merge (every row a resident prefix) when paged."""
+        def spec(x, sharding):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+        def prefilled(execs, w):
+            return jax.tree.map(lambda x: spec(x, x.sharding),
+                                execs[(w, plen)].out_info[1])
+
+        ring, draft = self._pages is None, self.draft_cache is not None
+        rbs = sorted({_bucket(n, self.B) for n in range(1, self.B + 1)})
+        runs = []                       # (part, W or None, row bucket)
+        if not self.overlap:
+            if ring or draft:
+                runs += [((False, ring, draft, False), _bucket(n, waves[-1]),
+                          _bucket(n, self.B)) for n in range(1, self.B + 1)]
+        else:
+            part = (self.continuous, ring, draft, True)
+            runs += [(part, w, rb) for w in waves for rb in rbs
+                     if rb <= w and (rb == 1 or not self.continuous)]
+            if not ring:
+                runs += [((self.continuous, False, False, True), None, rb)
+                         for rb in ([1] if self.continuous else rbs)]
+        for part, w, rb in runs:
+            stage, with_cache, with_draft, with_carry = part
+            cache, dcache, carry = self._merge_targets(part)
+            if with_carry and carry is None:   # built at the first boundary
+                carry = {k: spec(v, self._carry_sh[k])
+                         for k, v in self._st.items()}
+            targets = jax.tree.map(lambda x: spec(x, x.sharding),
+                                   (cache, dcache, carry))
+            new = (prefilled(self._prefill_exec, w) if with_cache else None)
+            dnew = (prefilled(self._draft_prefill_exec, w) if with_draft
+                    else None)
+            first = ks = None
+            if with_carry and w is not None:
+                first = jax.ShapeDtypeStruct((w,), jnp.int32,
+                                             sharding=self._repl)
+                ks = jax.ShapeDtypeStruct((w, 2, 2), jnp.uint32,
+                                          sharding=self._repl)
+            host = None
+            if with_carry:
+                host = {k: np.zeros((rb,) + v.shape[1:], v.dtype)
+                        for k, v in self._st.items() if k not in DEVICE_ROWS}
+                if stage:
+                    host["seq"] = np.zeros(rb, np.int32)
+            ix = self._merge_ix([0] * rb, [0] * rb,
+                                [(0, 0)] * rb if with_draft else None, host,
+                                (np.zeros(rb, bool), np.zeros(rb, np.int32),
+                                 np.zeros(rb, np.int32),
+                                 np.zeros((rb, 2), np.uint32)))
+            ix = jax.tree.map(lambda x: spec(x, self._repl), ix)
+            key = self._merge_key(part, new, dnew, first, rb)
+            self._merge_exec[key] = self._merge_jit(part).lower(
+                *targets, new, dnew, first, ks, ix).compile()
 
     @staticmethod
     def _make_prefill_dispatch(jit_fn, executables):
@@ -1442,11 +1554,7 @@ class Engine:
             self._count_prefill(toks, lens)
             tj, lj = self._prefill_args(toks, lens)
             logits, new_cache = self._prefill(self.params, tj, lj)
-        if self._pages is None:
-            # ring layout never skips (the gate needs the page registry)
-            slots = jnp.asarray([s for s, _ in wave])
-            self.cache = _merge_slot(self.cache, new_cache, slots)
-        else:
+        if self._pages is not None:
             rws, cols, phys = [], [], []
             for i, (slot, r) in enumerate(wave):
                 if rows[i] < 0:
@@ -1464,6 +1572,7 @@ class Engine:
                 self.cache = _merge_slot_paged(
                     self.cache, new_cache, jnp.asarray(rws),
                     jnp.asarray(cols), jnp.asarray(phys), self.page_size)
+        dnew = None
         if self.draft_cache is not None and toks is not None:
             # the layer draft consumes the same wave so its ring tracks
             # the target's (its logits here are irrelevant).  A skip
@@ -1471,11 +1580,12 @@ class Engine:
             # garbage until overwritten, which costs acceptance rate but
             # never correctness (streams are invariant to proposals).
             _, dnew = self._draft_prefill(self.draft_params, tj, lj)
-            dslots = [s for (s, _), ri in zip(wave, rows) if ri >= 0]
-            drows = [ri for ri in rows if ri >= 0]
-            self.draft_cache = _merge_slot(self.draft_cache, dnew,
-                                           jnp.asarray(dslots),
-                                           rows=jnp.asarray(drows))
+        # ring layout never skips (the gate needs the page registry): the
+        # wave's rows merge in order
+        self._merge([s for s, _ in wave], range(len(wave)),
+                    new=new_cache if self._pages is None else None,
+                    dnew=dnew, drafts=[(s, ri) for (s, _), ri in
+                                       zip(wave, rows) if ri >= 0])
         return logits
 
     def _admit_sample_first(self, reqs, first_lens, logits):
@@ -1652,7 +1762,7 @@ class Engine:
             if self.overlap:
                 self._ensure_dev_state()
                 self._scatter_rows(np.array([slot], np.int32),
-                                   {"ptab": row[None]}, {})
+                                   {"ptab": row[None]})
         self._update_pins()
 
     def _alloc_with_preemption(self, slot: int, need: int):
@@ -1810,8 +1920,7 @@ class Engine:
             if self.overlap:
                 self._ensure_dev_state()
                 rows_all = {k: np.asarray(st[k][slot])[None] for k in st}
-                self._scatter_rows(np.array([slot], np.int32),
-                                   rows_all, {})
+                self._scatter_rows(np.array([slot], np.int32), rows_all)
                 self._slot_epoch[slot] = self._dispatch_index
                 self._buf_epoch[slot] = self._dispatch_index
             self._update_pins()
@@ -1863,11 +1972,14 @@ class Engine:
 
     def _ensure_dev_state(self):
         if self._st_dev is None:
-            self._st_dev = {k: jax.device_put(v, self._carry_sh[k])
+            # from a copy: on the CPU a device_put of an aligned host
+            # buffer is zero-copy (may_alias=False too), and the host keeps
+            # writing its mirror while programs that read this carry queue
+            self._st_dev = {k: jax.device_put(v.copy(), self._carry_sh[k])
                             for k, v in self._st.items()}
 
     def _scatter_rows(self, slots_pad: np.ndarray, host_rows: dict,
-                      dev_rows: dict, guard_gen=None):
+                      guard_gen=None):
         """Scatter per-slot rows into the device carry.  ``slots_pad`` is
         bucket-padded with out-of-range index B; mode="drop" discards the
         pad rows, so bucketing never writes a real slot.
@@ -1883,10 +1995,115 @@ class Engine:
         if guard_gen is not None:
             ok = st["gen"][sl] == jnp.asarray(guard_gen)
             sl = jnp.where(ok, sl, self.B)
-        for k, rows in {**host_rows, **dev_rows}.items():
-            st[k] = st[k].at[sl].set(
-                jnp.asarray(rows).astype(st[k].dtype), mode="drop")
+        for k, rows in host_rows.items():
+            # a copy: rows may be views of the mirror (see _ensure_dev_state)
+            st[k] = st[k].at[sl].set(jnp.array(rows, st[k].dtype),
+                                     mode="drop")
         self._st_dev = st
+
+    def _merge(self, slots, rows, *, new=None, dnew=None, drafts=None,
+               host=None, pick=None, first=None, ks=None, stage=False):
+        """One admission merge, as one compiled program (_admission_merge):
+        prefill rows ``rows`` of ``new`` into ring slots ``slots`` — of
+        the resident pool, or of the continuous stage with ``stage`` —
+        the layer draft's (slot, row) pairs ``drafts`` of ``dnew``, and,
+        with ``host`` (host-known carry rows) and ``pick`` (full, eos,
+        left, keys0 per row), every carry row.  The pool, draft ring and
+        carry are donated (where donation takes effect) and come back with
+        the window's shardings, so the next window neither reshards nor
+        retraces."""
+        part = (stage, new is not None, dnew is not None, host is not None)
+        if not any(part[1:]):
+            return
+        if host is None:
+            first = ks = None
+        elif self.aot and first is not None:
+            first, ks = jax.device_put((first, ks), self._repl)
+        ix = self._merge_ix(slots, rows, drafts if dnew is not None else None,
+                            host, pick)
+        fn = self._merge_exec.get(
+            self._merge_key(part, new, dnew, first, len(ix["slots"])))
+        if fn is None:
+            fn = self._merge_jit(part)
+        cache, dcache, carry = fn(*self._merge_targets(part), new, dnew,
+                                  first, ks, ix)
+        if stage:
+            self._stage_dev = {**self._stage_dev, "seq": carry.pop("seq"),
+                               "rows": carry}
+            if cache is not None:
+                self._stage_dev["cache"] = cache
+            return
+        if cache is not None:
+            self.cache = cache
+        if dcache is not None:
+            self.draft_cache = dcache
+        if carry is not None:
+            self._st_dev = carry
+
+    def _merge_targets(self, part):
+        """(cache, draft cache, carry) a merge of ``part`` writes into;
+        the stage's carry is its rows plus its seq keys."""
+        stage, with_cache, with_draft, with_carry = part
+        if stage:
+            return (self._stage_dev.get("cache") if with_cache else None,
+                    None, {**self._stage_dev["rows"],
+                           "seq": self._stage_dev["seq"]})
+        return (self.cache if with_cache else None,
+                self.draft_cache if with_draft else None,
+                self._st_dev if with_carry else None)
+
+    def _merge_jit(self, part):
+        """The jitted merge for ``part`` = (stage, cache, draft, carry),
+        its outputs pinned to where the window reads them."""
+        fn = self._merge_jits.get(part)
+        if fn is None:
+            stage = part[0]
+            if stage:
+                sh = (self._stage_sh.get("cache"), None,
+                      {**self._stage_sh["rows"], "seq": self._stage_sh["seq"]})
+            else:
+                sh = (self._cache_shardings, self._draft_cache_shardings,
+                      self._carry_sh)
+            out = tuple(s if on else None for s, on in zip(sh, part[1:]))
+
+            def counted(*args):
+                self.trace_counts["merge"] += 1
+                return _admission_merge(*args)
+
+            fn = self._merge_jits[part] = jax.jit(
+                counted, donate_argnums=self._merge_donate, out_shardings=out)
+        return fn
+
+    @staticmethod
+    def _merge_key(part, new, dnew, first, rb: int):
+        """What selects a merge program: its parts, row bucket, and the
+        shapes of the wave it merges from."""
+        return part, rb, tuple((x.shape, x.dtype)
+                               for x in jax.tree.leaves((new, dnew, first)))
+
+    def _merge_ix(self, slots, rows, drafts=None, host=None, pick=None):
+        """A merge's row vectors (host numpy), padded to the slot-count
+        bucket: a pad slot is B (dropped), a pad row 0."""
+        rb = _bucket(len(slots), self.B)
+
+        def pad(a, dtype, fill=0):
+            a = np.asarray(a, dtype)
+            out = np.full((rb,) + a.shape[1:], fill, dtype)
+            out[: a.shape[0]] = a
+            return out
+
+        ix = {"slots": pad(slots, np.int32, self.B),
+              "rows": pad(rows, np.int32)}
+        if drafts is not None:
+            ix["dslots"] = pad([s for s, _ in drafts], np.int32, self.B)
+            ix["drows"] = pad([r for _, r in drafts], np.int32)
+        if host is not None:
+            ix["host"] = {k: pad(v, v.dtype) for k, v in host.items()}
+            full, eos, left, keys0 = pick
+            ix |= {"full": pad(full, bool), "eos": pad(eos, np.int32),
+                   "left": pad(left, np.int32),
+                   "keys0": pad(keys0, np.uint32)}
+        return ix
 
     def _prepare_wave(self, reqs) -> StagedWave:
         """Stage a wave OFF the admission path: bucket the prompts,
@@ -2004,12 +2221,7 @@ class Engine:
         st = self._st
         prow = ((lambda i: i) if wv.rows is None
                 else (lambda i: wv.rows[i]))
-        if self._pages is None:
-            # ring layout never skips: rows is the identity mapping
-            slots = jnp.asarray([s for s, _ in placed])
-            self.cache = _merge_slot(self.cache, wv.new_cache, slots,
-                                     rows=jnp.asarray(idx))
-        else:
+        if self._pages is not None:
             rws, cols, phys = [], [], []
             for i, (slot, r) in zip(idx, placed):
                 if prow(i) < 0:
@@ -2027,13 +2239,6 @@ class Engine:
                 self.cache = _merge_slot_paged(
                     self.cache, wv.new_cache, jnp.asarray(rws),
                     jnp.asarray(cols), jnp.asarray(phys), self.page_size)
-        if wv.draft_new_cache is not None:
-            dslots = [s for i, (s, _) in zip(idx, placed) if prow(i) >= 0]
-            drows = [prow(i) for i in idx if prow(i) >= 0]
-            if dslots:
-                self.draft_cache = _merge_slot(
-                    self.draft_cache, wv.draft_new_cache,
-                    jnp.asarray(dslots), rows=jnp.asarray(drows))
         for i, (slot, r) in zip(idx, placed):
             self._admit_bookkeep(slot, r, wv.specs[i], wv.first_lens[i],
                                  wv.eos[i])
@@ -2043,51 +2248,22 @@ class Engine:
             self._slot_epoch[slot] = self._dispatch_index
             self._buf_epoch[slot] = self._dispatch_index
         # host-known carry rows from the mirror the bookkeeping just
-        # wrote; tok/keys/act depend on the sampled first token and stay
-        # on device.  Pad to a slot-count bucket (mode="drop" pads).
-        n = len(placed)
-        Wb = _bucket(n, self.B)
-        slots_pad = np.full(Wb, self.B, np.int32)
-        slots_pad[:n] = [s for s, _ in placed]
-        host_rows = {}
-        for k, arr in st.items():
-            if k in ("tok", "keys", "act"):
-                continue
-            rows = np.zeros((Wb,) + arr.shape[1:], arr.dtype)
-            for i, (slot, _) in enumerate(placed):
-                rows[i] = arr[slot]
-            host_rows[k] = rows
-        pad_ix = np.zeros(Wb, np.int64)
-        pad_ix[:n] = idx
-        sel = jnp.asarray(pad_ix)
-        if wv.first is None:
-            # all-skip wave: no sampled first tokens; every row starts
-            # active with its base key, feeding from the ingest buffer
-            dev_rows = {
-                "tok": jnp.zeros(Wb, jnp.int32),
-                "act": jnp.ones(Wb, bool),
-                "keys": jnp.asarray(wv.keys0)[sel],
-            }
-        else:
-            prow_ix = np.zeros(Wb, np.int64)
-            prow_ix[:n] = [max(prow(i), 0) for i in idx]
-            rsel = jnp.asarray(prow_ix)      # per-prefill-row gathers
-            full_d = jnp.asarray(wv.full)[sel]
-            eos_d = jnp.asarray(wv.eos)[sel]
-            first_sel = wv.first[rsel]
-            left_d = jnp.asarray(np.array(
-                [wv.reqs[i].max_new_tokens - 1 for i in idx]
-                + [0] * (Wb - n), np.int32))
-            dev_rows = {
-                "tok": jnp.where(full_d, first_sel, 0),
-                # a full-prompt row can die at its very first token (eos,
-                # or an exhausted budget) — the checks the window applies
-                "act": jnp.where(full_d, (first_sel != eos_d)
-                                 & (left_d > 0), True),
-                "keys": jnp.where(full_d[:, None], wv.ks[rsel][:, 0],
-                                  jnp.asarray(wv.keys0)[sel]),
-            }
-        self._scatter_rows(slots_pad, host_rows, dev_rows)
+        # wrote; tok/keys/act depend on the sampled first token and are
+        # picked on device.  A skip member (no prefill row) picks row 0,
+        # which its full=False never reads.
+        slots = [s for s, _ in placed]
+        self._merge(
+            slots, [max(prow(i), 0) for i in idx],
+            new=wv.new_cache if self._pages is None else None,
+            dnew=wv.draft_new_cache,
+            drafts=[(s, prow(i)) for i, s in zip(idx, slots)
+                    if prow(i) >= 0],
+            host={k: arr[slots] for k, arr in st.items()
+                  if k not in DEVICE_ROWS},
+            pick=(wv.full[idx], wv.eos[idx],
+                  [wv.reqs[i].max_new_tokens - 1 for i in idx],
+                  wv.keys0[idx]),
+            first=wv.first, ks=wv.ks)
         entries = [(r, prow(i)) for i, (_, r) in zip(idx, placed)
                    if wv.full[i]]
         if entries:
@@ -2186,13 +2362,6 @@ class Engine:
                         jnp.asarray(cols), jnp.asarray(phys),
                         self.page_size)
             pages = list(mapping)
-        else:
-            self._stage_dev = {
-                **self._stage_dev,
-                "cache": _merge_slot(self._stage_dev["cache"],
-                                     wv.new_cache, jnp.asarray([q]),
-                                     rows=jnp.asarray([ri])),
-            }
         row, pending = self._stage_bookkeep(r, wv.specs[i],
                                             wv.first_lens[i], wv.eos[i])
         if mapping is not None:
@@ -2202,38 +2371,16 @@ class Engine:
         ent = StagedEntry(req=r, host_row=row, pending=pending,
                           pages=pages, seq=seq_val, keys0=wv.keys0[i],
                           full=bool(wv.full[i]))
-        if ri < 0:
-            # skip member: no sampled first token; it starts feeding
-            # from the ingest buffer with its base key
-            dev_row = {
-                "tok": jnp.zeros((), jnp.int32),
-                "act": jnp.asarray(True),
-                "keys": jnp.asarray(ent.keys0),
-            }
-        else:
-            full_d = jnp.asarray(bool(wv.full[i]))
-            eos_d = jnp.int32(int(wv.eos[i]))
-            left0 = jnp.int32(r.max_new_tokens - 1)
-            first_i = wv.first[ri]
-            dev_row = {
-                "tok": jnp.where(full_d, first_i, 0),
-                "act": jnp.where(full_d, (first_i != eos_d) & (left0 > 0),
-                                 True),
-                "keys": jnp.where(full_d, wv.ks[ri, 0],
-                                  jnp.asarray(ent.keys0)),
-            }
-        rows_dev = dict(self._stage_dev["rows"])
-        for k, v in row.items():
-            if k in ("tok", "act", "keys"):
-                continue
-            rows_dev[k] = rows_dev[k].at[q].set(
-                jnp.asarray(v).astype(rows_dev[k].dtype))
-        for k, v in dev_row.items():
-            rows_dev[k] = rows_dev[k].at[q].set(v.astype(rows_dev[k].dtype))
-        self._stage_dev = {
-            **self._stage_dev, "rows": rows_dev,
-            "seq": self._stage_dev["seq"].at[q].set(seq_val),
-        }
+        # a skip member (ri < 0) is never full: no sampled first token, it
+        # starts feeding from the ingest buffer with its base key
+        host = {k: v[None] for k, v in row.items() if k not in DEVICE_ROWS}
+        host["seq"] = np.array([seq_val], np.int32)
+        self._merge([q], [max(ri, 0)],
+                    new=wv.new_cache if self._pages is None else None,
+                    host=host,
+                    pick=([wv.full[i]], [wv.eos[i]], [r.max_new_tokens - 1],
+                          wv.keys0[i][None]),
+                    first=wv.first, ks=wv.ks, stage=True)
         self._stage_tab[q] = ent
         self._stage_by_seq[seq_val] = (q, ent)
         if ent.full:
@@ -2281,7 +2428,7 @@ class Engine:
         if self.continuous:
             gg = np.zeros(R_, np.int32)
             gg[:n] = st["gen"][slots]
-        self._scatter_rows(slots_pad, host_rows, {}, guard_gen=gg)
+        self._scatter_rows(slots_pad, host_rows, guard_gen=gg)
 
     def _dispatch_window(self) -> bool:
         """One pipeline boundary's front half: launch the next window on
@@ -2483,7 +2630,7 @@ class Engine:
         if self.continuous:
             gg = np.zeros(Rb, np.int32)
             gg[:n] = st["gen"][degrade]
-        self._scatter_rows(slots_pad, {"spec_on": np.zeros(Rb, bool)}, {},
+        self._scatter_rows(slots_pad, {"spec_on": np.zeros(Rb, bool)},
                            guard_gen=gg)
 
     def _window_item(self, w: InflightWindow, base=None, swaps=None):

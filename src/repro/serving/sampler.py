@@ -58,9 +58,33 @@ class SamplingParams:
             raise ValueError("top_p must be in (0, 1]")
 
     def slot_key(self, uid: int) -> np.ndarray:
-        """The (2,) uint32 PRNG key a slot starts from for this request."""
-        return np.asarray(
-            jax.random.fold_in(jax.random.PRNGKey(self.seed), uid))
+        """The (2,) uint32 PRNG key a slot starts from for this request,
+        derived on the host: the Threefry-2x32 hash of ``(0, uid)`` under
+        the key ``(0, seed)`` (both taken mod 2**32) — what JAX's
+        ``fold_in(PRNGKey(seed), uid)`` gives under its default config.
+        A device-side derivation would read its key back behind every
+        decode window in flight (about 2 s an admission on a TPU v5e at
+        a pipeline depth of 3)."""
+        return np.array(_threefry2x32((0, self.seed & _U32), (0, uid & _U32)),
+                        np.uint32)
+
+
+_U32 = 0xFFFFFFFF
+
+
+def _threefry2x32(key, x):
+    """The Threefry-2x32 hash of one count pair under ``key``, on host
+    ints: JAX's own (``jax._src.prng._threefry2x32_lowering``), five
+    groups of four rounds with a key injection after each."""
+    ks = (key[0], key[1], key[0] ^ key[1] ^ 0x1BD11BDA)
+    x0, x1 = (x[0] + ks[0]) & _U32, (x[1] + ks[1]) & _U32
+    for i in range(5):
+        for r in ((13, 15, 26, 6), (17, 29, 16, 24))[i % 2]:
+            x0 = (x0 + x1) & _U32
+            x1 = (((x1 << r) | (x1 >> (32 - r))) & _U32) ^ x0
+        x0 = (x0 + ks[(i + 1) % 3]) & _U32
+        x1 = (x1 + ks[(i + 2) % 3] + i + 1) & _U32
+    return x0, x1
 
 
 GREEDY = SamplingParams()

@@ -327,6 +327,171 @@ class TestAdaptiveSpec:
                    adaptive_spec=True)
 
 
+def _rand_like(g, x, lead=None):
+    """Random array shaped like ``x`` (batch dim resized to ``lead``)."""
+    shape = x.shape if lead is None else lead
+    dt = np.dtype(x.dtype)
+    if dt == bool:
+        return g.random(shape) < 0.5
+    if np.issubdtype(dt, np.integer):
+        info = np.iinfo(dt)
+        return g.integers(max(info.min, -1000), min(info.max, 1000), shape,
+                          dtype=dt)
+    return g.standard_normal(shape).astype(dt)
+
+
+def _rand_wave(g, pool, w):
+    """A prefill cache of ``w`` rows shaped like the pool ``pool``."""
+    def one(path, x):
+        d = 1 if getattr(path[0], "key", None) == "blocks" else 0
+        lead = x.shape[:d] + (w,) + x.shape[d + 1:]
+        return jnp.asarray(_rand_like(g, x, lead))
+    return jax.tree_util.tree_map_with_path(one, pool)
+
+
+def _eager_merge(cache, dcache, carry, new, dnew, first, ks, slots, rows,
+                 host, full, eos, left, keys0, B):
+    """The admission merge as eager per-leaf gathers and scatters: the
+    reference the compiled program must reproduce bit for bit."""
+    sl, rw = jnp.asarray(slots), jnp.asarray(rows)
+
+    def merge_slot(pool_cache, new_cache):
+        def one(path, pool, src):
+            if getattr(path[0], "key", None) == "blocks":
+                return pool.at[:, sl].set(src[:, rw].astype(pool.dtype))
+            return pool.at[sl].set(src[rw].astype(pool.dtype))
+        return jax.tree_util.tree_map_with_path(one, pool_cache, new_cache)
+
+    cache = merge_slot(cache, new)
+    if dcache is not None:
+        dcache = merge_slot(dcache, dnew)
+    n = len(slots)
+    wb = 1 << (n - 1).bit_length()
+    pad = wb - n
+    slots_pad = jnp.asarray(list(slots) + [B] * pad)
+    rsel = jnp.asarray(list(rows) + [0] * pad)
+    full_d = jnp.asarray(np.concatenate([full, np.zeros(pad, bool)]))
+    eos_d = jnp.asarray(np.concatenate([eos, np.zeros(pad, np.int32)]))
+    left_d = jnp.asarray(np.concatenate([left, np.zeros(pad, np.int32)]))
+    keys0_d = jnp.asarray(np.concatenate([keys0,
+                                          np.zeros((pad, 2), np.uint32)]))
+    first_sel = first[rsel]
+    out = dict(carry)
+    rows_all = {k: np.concatenate([v, np.zeros((pad,) + v.shape[1:],
+                                               v.dtype)])
+                for k, v in host.items()}
+    rows_all |= {
+        "tok": jnp.where(full_d, first_sel, 0),
+        "act": jnp.where(full_d, (first_sel != eos_d) & (left_d > 0), True),
+        "keys": jnp.where(full_d[:, None], ks[rsel][:, 0], keys0_d),
+    }
+    for k, v in rows_all.items():
+        out[k] = out[k].at[slots_pad].set(
+            jnp.asarray(v).astype(out[k].dtype), mode="drop")
+    return cache, dcache, out
+
+
+class TestCompiledMerge:
+    """The admission merge is one compiled program (pool cache, layer-
+    draft ring and carry rows together): bitwise what the eager per-leaf
+    scatters gave, nothing outside the merged slots touched, and no
+    retrace for a second round of merges of already-seen shapes."""
+
+    @pytest.mark.parametrize("n", [1, 2, 3])          # row buckets 1, 2, 4
+    @pytest.mark.parametrize("draft", [None, "layers:2"])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_matches_eager_merge(self, case, draft, n):
+        cfg, params = _model(case)
+        kw = {"spec_depth": 2, "draft": draft} if draft else {}
+        B, W = 4, 4
+        eng = Engine(cfg, params, max_slots=B, max_len=40, overlap=True,
+                     **kw)
+        g = np.random.default_rng(n + 7 * len(case))
+
+        def fill(tree, shardings):           # placed as the engine's own
+            return jax.device_put(jax.tree.map(lambda x: _rand_like(g, x),
+                                               tree), shardings)
+        eng.cache = fill(eng.cache, eng._cache_shardings)
+        if draft:
+            eng.draft_cache = fill(eng.draft_cache,
+                                   eng._draft_cache_shardings)
+        eng._st_dev = fill(eng._st, eng._carry_sh)
+        traced = None
+        for _ in range(2):                 # second round: seen shapes
+            slots = [int(s) for s in g.permutation(B)[:n]]
+            rows = [int(r) for r in g.permutation(W)[:n]]
+            new = _rand_wave(g, eng.cache, W)
+            dnew = _rand_wave(g, eng.draft_cache, W) if draft else None
+            first = jnp.asarray(g.integers(0, cfg.vocab_size, W, np.int32))
+            ks = jnp.asarray(g.integers(0, 2 ** 32, (W, 2, 2), np.uint32))
+            host = {k: _rand_like(g, v, (n,) + v.shape[1:])
+                    for k, v in eng._st.items()
+                    if k not in ("tok", "act", "keys")}
+            full = g.random(n) < 0.7
+            eos = g.integers(0, cfg.vocab_size, n, np.int32)
+            left = g.integers(0, 3, n).astype(np.int32)
+            keys0 = g.integers(0, 2 ** 32, (n, 2), np.uint32)
+            before = jax.tree.map(np.asarray, (eng.cache, eng.draft_cache,
+                                               eng._st_dev))
+            want = _eager_merge(eng.cache, eng.draft_cache, eng._st_dev,
+                                new, dnew, first, ks, slots, rows, host,
+                                full, eos, left, keys0, B)
+            eng._merge(slots, rows, new=new, dnew=dnew,
+                       drafts=list(zip(slots, rows)) if draft else None,
+                       host=host, pick=(full, eos, left, keys0),
+                       first=first, ks=ks)
+            got = (eng.cache, eng.draft_cache, eng._st_dev)
+            jax.tree.map(np.testing.assert_array_equal,
+                         jax.tree.map(np.asarray, got),
+                         jax.tree.map(np.asarray, want))
+            others = [s for s in range(B) if s not in slots]
+
+            def unchanged(path, a, b):
+                d = 1 if getattr(path[0], "key", None) == "blocks" else 0
+                np.testing.assert_array_equal(np.take(a, others, axis=d),
+                                              np.take(b, others, axis=d))
+            for a, b in zip(jax.tree.map(np.asarray, got[:2]), before[:2]):
+                jax.tree_util.tree_map_with_path(unchanged, a, b)
+            for k, v in got[2].items():
+                np.testing.assert_array_equal(np.asarray(v)[others],
+                                              before[2][k][others])
+            if traced is None:
+                traced = eng.trace_counts["merge"]
+                assert traced == 1
+        assert eng.trace_counts["merge"] == traced, "merge retraced"
+
+
+def _aligned_copy(a, align=64):
+    """``a`` copied into a buffer aligned to ``align`` bytes, the layout
+    in which a device_put on the CPU aliases host memory."""
+    raw = np.empty(a.nbytes + align, np.uint8)
+    off = (-raw.ctypes.data) % align
+    out = raw[off: off + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+class TestHostMirror:
+    def test_device_carry_never_aliases_mirror(self):
+        """The overlapped engine keeps writing its host mirror while the
+        programs that read the device carry may still be queued: the
+        carry it uploads must be a copy, even where the backend could
+        alias the mirror's memory."""
+        cfg, params = _model("latent")
+        eng = Engine(cfg, params, max_slots=4, max_len=40, overlap=True)
+        eng._st = {k: _aligned_copy(v) for k, v in eng._st.items()}
+        eng._ensure_dev_state()
+        want = jax.tree.map(np.array, eng._st_dev)
+        for v in eng._st.values():
+            if v.dtype == bool:
+                np.logical_not(v, out=v)
+            else:
+                v += 1
+        jax.tree.map(np.testing.assert_array_equal,
+                     jax.tree.map(np.asarray, eng._st_dev), want)
+        eng.close()
+
+
 class TestAOT:
     def test_aot_no_retrace_and_stream_parity(self):
         """AOT compiles the window exactly once and every prefill bucket

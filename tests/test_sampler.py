@@ -163,6 +163,24 @@ class TestSamplingParamsValidation:
             SamplingParams(**bad)
 
 
+class TestSlotKey:
+    @pytest.mark.parametrize("seed", [0, 11, 2 ** 31 - 1, 2 ** 31, -7,
+                                      2 ** 40 + 3])
+    def test_host_key_is_jax_fold_in(self, seed):
+        """The host-computed slot key is JAX's fold_in(PRNGKey(seed),
+        uid) under JAX's default config, bit for bit."""
+        g = np.random.default_rng(seed & 0xFFFF)
+        uids = [0, 1, 2 ** 31, 2 ** 32 - 1] + [
+            int(u) for u in g.integers(0, 2 ** 32, 16)]
+        sp = SamplingParams(seed=seed)
+        for uid in uids:
+            want = np.asarray(jax.random.fold_in(jax.random.PRNGKey(seed),
+                                                 uid))
+            got = sp.slot_key(uid)
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want, err_msg=str(uid))
+
+
 class TestEngineSampling:
     """The determinism contracts through the full fused decode loop."""
 
