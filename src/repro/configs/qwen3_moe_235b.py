@@ -1,5 +1,7 @@
-"""qwen3-moe-235b-a22b [moe] — 94L d_model=4096 64H (GQA kv=4) d_ff=1536
-vocab=151936, MoE 128 experts top-8, qk-norm.  [hf:Qwen/Qwen3-30B-A3B; hf]
+"""qwen3-moe-235b-a22b [moe] — 94L d_model=4096 64H (GQA kv=4) expert
+width 1536, vocab=151936, MoE 128 experts top-8 (renormalised), every
+layer sparse, qk-norm, untied embeddings.
+[hf:Qwen/Qwen3-235B-A22B config.json]
 
 GQA kv=4 -> a single HSR group of 4 per layer; ReCalKV fully applies (the
 MoE change is FFN-only).

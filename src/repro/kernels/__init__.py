@@ -4,6 +4,7 @@
                    reconstruction — never materializes K in HBM)
   latent_decode_q  the same, over int8 latents (Table-4 quantized cache)
   flash_prefill    causal / sliding-window flash attention
+  expert_gmm       grouped matmul over the experts a device holds (MoE)
 
 Each kernel has a pure-jnp oracle in ref.py and a jit wrapper in ops.py;
 the model's ``attn_backend="pallas"`` paths call the ops wrappers.
@@ -11,6 +12,7 @@ Interpret mode is platform-derived (ops.default_interpret): Python-level
 validation off-TPU, Mosaic lowering on TPU.
 """
 
+from repro.kernels.expert_gmm import expert_gmm
 from repro.kernels.flash_prefill import flash_prefill_attention
 from repro.kernels.latent_decode import latent_decode_attention
 from repro.kernels.latent_decode_q import latent_decode_attention_quant
@@ -19,6 +21,7 @@ from repro.kernels.ops import (default_interpret, dense_decode, flash_prefill,
 
 __all__ = [
     "default_interpret",
+    "expert_gmm",
     "dense_decode",
     "flash_prefill",
     "flash_prefill_attention",

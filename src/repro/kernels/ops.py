@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
+from repro.kernels.expert_gmm import expert_gmm as _expert_gmm
 from repro.kernels.flash_prefill import flash_prefill_attention
 from repro.kernels.latent_decode import (NEG_INF, latent_decode_attention,
                                          latent_decode_attention_mq,
@@ -728,7 +729,17 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int | None = None,
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
+def expert_gmm(lhs, rhs, group_sizes, *, interpret: bool | None = None):
+    """Grouped matmul over held experts (see kernels/expert_gmm.py): lhs
+    (m, K) rows sorted by expert, rhs (G, K, N), group_sizes (G,) int32.
+    Runs on one device; an expert-parallel mesh would exchange rows
+    first, which this layer does not do."""
+    return _expert_gmm(lhs, rhs, group_sizes,
+                       interpret=_resolve_interpret(interpret))
+
+
 __all__ = [
+    "expert_gmm",
     "decode_bias", "verify_bias", "rope_tables_for",
     "group_queries", "ungroup_outputs",
     "group_queries_mq", "ungroup_outputs_mq",

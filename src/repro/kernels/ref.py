@@ -8,6 +8,11 @@ Shapes (decode):
   cos/sin (B, S, dh/2)    rotation tables for the *stored* positions
   bias   (B, S)           additive mask (0 valid / -inf invalid)
   out    (B, G, Hg, r_v)  per-head latent attention outputs
+
+Grouped matmul (held experts):
+  lhs    (m, K)           rows sorted by group
+  rhs    (G, K, N)        one weight per group
+  group_sizes (G,)        rows per group, summing to at most m
 """
 
 from __future__ import annotations
@@ -73,3 +78,17 @@ def flash_prefill_attention(q, k, v, *, causal=True, window=None, scale=None):
     w = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", w, v.astype(jnp.float32))
     return o.reshape(B, T, H, v.shape[-1])
+
+
+def expert_gmm(lhs, rhs, group_sizes):
+    """Reference grouped matmul: row r of group g is lhs[r] @ rhs[g] in
+    float32; rows past the last group are zero."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    row = jnp.arange(lhs.shape[0])[:, None]
+    x = lhs.astype(jnp.float32)
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    for g in range(rhs.shape[0]):
+        mine = (row >= starts[g]) & (row < ends[g])
+        out = out + jnp.where(mine, x @ rhs[g].astype(jnp.float32), 0.0)
+    return out

@@ -33,13 +33,34 @@ SELF_ATTN_KINDS = ("attn", "attn_dense", "local", "attn_cross")
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts: top-k of a softmax router, the k weights
+    renormalised to sum to 1.  The router always spans all
+    ``num_experts``; this device holds experts ``first_held ..
+    first_held + num_held - 1`` (all of them by default) and computes only
+    their part of each token's output, as one chip of an expert-parallel
+    layer does."""
+
     num_experts: int
     top_k: int
     d_expert: int
     num_shared: int = 0          # shared (always-on) experts, DeepSeek style
     first_k_dense: int = 0       # leading layers that keep a dense FFN
-    capacity_factor: float = 1.25
     router_zloss: float = 1e-3
+    first_held: int = 0
+    num_held: int | None = None  # None: every expert is held here
+
+    def __post_init__(self):
+        if not 0 < self.top_k <= self.num_experts:
+            raise ValueError("top_k must lie in 1..num_experts")
+        if (self.first_held < 0 or self.held_count < 1
+                or self.first_held + self.held_count > self.num_experts):
+            raise ValueError(
+                f"held experts {self.first_held}..+{self.held_count} do not "
+                f"lie within the {self.num_experts} routed")
+
+    @property
+    def held_count(self) -> int:
+        return self.num_experts if self.num_held is None else self.num_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -248,7 +269,7 @@ class ModelConfig:
         if self.moe is None:
             return 3 * d * self.d_ff
         m = self.moe
-        experts = m.top_k if active_only else m.num_experts
+        experts = min(m.top_k, m.held_count) if active_only else m.held_count
         return (
             3 * d * m.d_expert * (experts + m.num_shared)
             + d * m.num_experts  # router

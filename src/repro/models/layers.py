@@ -16,7 +16,6 @@ see DESIGN.md §2).
 
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Any
 
@@ -357,85 +356,113 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
 
 
 # ---------------------------------------------------------------------------
-# FFN: dense SwiGLU & capacity-routed MoE
+# FFN: dense SwiGLU & dropless held-expert MoE
 # ---------------------------------------------------------------------------
+
+# What a layer adds besides its output: the training loss term, and under
+# cfg.moe the decode counters of its routing (int32: token-expert pairs
+# routed over all experts, pairs whose expert is held here, and non-empty
+# held groups), summed over the layers like the loss.
+MOE_COUNTERS = ("moe_decode_rows_routed", "moe_decode_rows",
+                "moe_decode_groups")
+
+
+def zero_aux(cfg: ModelConfig) -> Params:
+    aux = {"loss": jnp.float32(0.0)}
+    if cfg.moe is not None:
+        aux["moe"] = jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
+    return aux
+
+
+def add_aux(a: Params, b: Params) -> Params:
+    return jax.tree.map(jnp.add, a, b)
+
 
 def swiglu(p: Params, x: jax.Array) -> jax.Array:
     return (jax.nn.silu(x @ p["wg"]) * (x @ p["wi"])) @ p["wo"]
 
 
-def _expert_positions(sel: jax.Array, num_experts: int) -> jax.Array:
-    """GShard-style position-in-expert.  sel: (N, k) -> pos (N, k) int32."""
-    N, k = sel.shape
-    counts = jnp.zeros((num_experts,), jnp.int32)
-    pos = []
-    for j in range(k):
-        oh = jax.nn.one_hot(sel[:, j], num_experts, dtype=jnp.int32)  # (N, E)
-        within = jnp.cumsum(oh, axis=0) - oh                          # prior same-expert
-        pos.append(jnp.take_along_axis(
-            within + counts[None, :], sel[:, j : j + 1], axis=1)[:, 0])
-        counts = counts + oh.sum(axis=0)
-    return jnp.stack(pos, axis=1)
+def _grouped(rows, w, sizes, use_kernel: bool):
+    """rows (m, K) sorted by held expert @ w (E_held, K, N) per group."""
+    if use_kernel:
+        return kops.expert_gmm(rows, w, sizes)
+    return jax.lax.ragged_dot(rows, w, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(rows.dtype)
 
 
-def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
-    """Capacity-dispatched top-k MoE.  Returns (out, aux_loss).
+def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
+            valid: jax.Array | None = None,
+            use_kernel: bool = False) -> tuple[jax.Array, Params]:
+    """Dropless top-k MoE over the experts this device holds.  Returns
+    (out, aux).
 
-    Dispatch is index-based (gather into an (E, C, d) buffer, scatter-add
-    back) — never materializes a (N, E, C) one-hot.  See DESIGN.md §3.
-    """
+    Every real token is routed over all ``num_experts`` (float32 softmax,
+    top-k, renormalised to sum to 1); the (token, held expert)
+    pairs are computed exactly, with no capacity, so a token's output never
+    depends on its batch-mates.  The absent experts' part is left out: the
+    result is this device's share of the layer.  ``valid`` (B, T) bool
+    marks real rows; pad rows and idle slots are routed to no expert.
+    ``use_kernel`` runs the grouped products in the Pallas kernel, else in
+    ``jax.lax.ragged_dot``."""
     m = cfg.moe
     B, T, d = x.shape
     N = B * T
     E, k = m.num_experts, m.top_k
-    cap = max(8, int(math.ceil(N * k / E * m.capacity_factor / 8.0)) * 8)
+    n_held = m.held_count
 
     xt = x.reshape(N, d)
-    logits = (xt @ p["router"]).astype(jnp.float32)        # (N, E)
+    logits = jnp.matmul(xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)     # (N, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    w, sel = jax.lax.top_k(probs, k)                       # (N, k)
-    w = w / (w.sum(axis=-1, keepdims=True) + 1e-9)
+    w, sel = jax.lax.top_k(probs, k)                             # (N, k)
+    w = w / w.sum(axis=-1, keepdims=True)
 
     # Aux losses: load-balance + router z-loss.
     me = probs.mean(axis=0)
     ce = jnp.zeros((E,), jnp.float32).at[sel.reshape(-1)].add(1.0) / (N * k)
-    aux = E * jnp.sum(me * ce) + m.router_zloss * jnp.mean(
+    loss = E * jnp.sum(me * ce) + m.router_zloss * jnp.mean(
         jax.nn.logsumexp(logits, axis=-1) ** 2
     )
 
-    pos = _expert_positions(sel, E)                        # (N, k)
-    keep = pos < cap
-    slot = jnp.where(keep, sel * cap + pos, E * cap)       # overflow -> sink
+    live = (jnp.ones((N,), bool) if valid is None
+            else valid.reshape(N).astype(bool))
+    local = sel - m.first_held
+    held = live[:, None] & (local >= 0) & (local < n_held)       # (N, k)
+    group = jnp.where(held, local, n_held).reshape(-1)           # (N*k,)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[group].add(1)[:n_held]
 
-    # token id occupying each expert slot (sink row E*cap absorbs drops)
-    tok_ids = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32)[:, None], (N, k))
-    token_for_slot = jnp.full((E * cap + 1,), 0, jnp.int32).at[
-        slot.reshape(-1)].set(tok_ids.reshape(-1), mode="drop")
-    filled = jnp.zeros((E * cap + 1,), jnp.bool_).at[
-        slot.reshape(-1)].set(True, mode="drop")
-    w_for_slot = jnp.zeros((E * cap + 1,), jnp.float32).at[
-        slot.reshape(-1)].set(w.reshape(-1), mode="drop")
-
-    buf = jnp.take(xt, token_for_slot[: E * cap], axis=0)  # (E*cap, d)
-    buf = jnp.where(filled[: E * cap, None], buf, 0).reshape(E, cap, d)
-    h = jnp.einsum("ecd,edf->ecf", buf, p["wg"])
-    hi = jnp.einsum("ecd,edf->ecf", buf, p["wi"])
-    y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(h) * hi, p["wo"])
-    y = y.reshape(E * cap, d)
-
-    scale = (w_for_slot[: E * cap] * filled[: E * cap]).astype(y.dtype)
-    out = jnp.zeros((N, d), y.dtype).at[token_for_slot[: E * cap]].add(
-        y * scale[:, None]
-    )
+    rows = jnp.take(xt, order // k, axis=0)                      # (N*k, d)
+    h = _grouped(rows, p["wg"], sizes, use_kernel)
+    u = _grouped(rows, p["wi"], sizes, use_kernel)
+    hu = (jax.nn.silu(h.astype(jnp.float32))
+          * u.astype(jnp.float32)).astype(x.dtype)
+    y = _grouped(hu, p["wo"], sizes, use_kernel)                 # (N*k, d)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(N * k, dtype=order.dtype))
+    y = jnp.take(y, back, axis=0).reshape(N, k, d).astype(jnp.float32)
+    out = jnp.sum(jnp.where(held[..., None], y * w[..., None], 0.0), axis=1)
+    out = out.astype(x.dtype)
     if m.num_shared:
         out = out + swiglu(p["shared"], xt)
-    return out.reshape(B, T, d), aux
+
+    counts = jnp.stack([live.sum() * k, held.sum(),
+                        (sizes > 0).sum()]).astype(jnp.int32)
+    return out.reshape(B, T, d), {"loss": loss, "moe": counts}
 
 
-def ffn(p: Params, x: jax.Array, cfg: ModelConfig, dense: bool) -> tuple[jax.Array, jax.Array]:
+def ffn(p: Params, x: jax.Array, cfg: ModelConfig, dense: bool,
+        valid: jax.Array | None = None,
+        use_kernel: bool = False) -> tuple[jax.Array, Params]:
+    """The block's FFN: dense SwiGLU (``valid`` ignored) or, under cfg.moe
+    and not ``dense``, :func:`moe_ffn`.  Returns (out, aux) with aux as
+    :func:`zero_aux` builds it."""
     if cfg.moe is not None and not dense:
-        return moe_ffn(p, x, cfg)
-    return swiglu(p, x), jnp.float32(0.0)
+        return moe_ffn(p, x, cfg, valid,
+                       use_kernel=use_kernel and cfg.attn_backend == "pallas")
+    return swiglu(p, x), zero_aux(cfg)
 
 
 # ---------------------------------------------------------------------------

@@ -104,9 +104,10 @@ def init_ffn_params(cfg: ModelConfig, key, *, dense: bool, stack=None) -> Params
             "wo": _dense_init(ks[2], (f, d), f ** -0.5, dt, stack),
         }
     m = cfg.moe
-    E, f = m.num_experts, m.d_expert
+    E, f = m.held_count, m.d_expert
     p = {
-        "router": _dense_init(ks[0], (d, E), d ** -0.5, jnp.float32, stack),
+        "router": _dense_init(ks[0], (d, m.num_experts), d ** -0.5,
+                              jnp.float32, stack),
         "wi": _dense_init(ks[1], (E, d, f), d ** -0.5, dt, stack),
         "wg": _dense_init(ks[2], (E, d, f), d ** -0.5, dt, stack),
         "wo": _dense_init(ks[3], (E, f, d), f ** -0.5, dt, stack),
@@ -232,7 +233,7 @@ def _theta(cfg: ModelConfig, kind: str) -> float:
 def block_full(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
                ctx: dict, want_cache: bool):
     """One block over a full (B, T, d) sequence.  Returns (x, cache, aux)."""
-    aux = jnp.float32(0.0)
+    aux = L.zero_aux(cfg)
     cache = None
     pos = ctx["positions"]
     causal = ctx.get("causal", True)
@@ -243,7 +244,7 @@ def block_full(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         x = x + y
         if kind == "rglru":
             h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, dense=True)
-            x, aux = x + h, aux + a
+            x, aux = x + h, L.add_aux(aux, a)
         cache = state if want_cache else None
         return x, cache, aux
 
@@ -259,7 +260,7 @@ def block_full(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
             cache = {"cross": {"k": src[0], "v": src[1]}} if want_cache else None
         x = x + y
         h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, dense=True)
-        return x + h, cache, aux + a
+        return x + h, cache, L.add_aux(aux, a)
 
     # self-attention kinds.  The pallas backend applies to inference
     # passes only (want_cache=True, i.e. prefill): the kernels have no
@@ -301,9 +302,10 @@ def block_full(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         x = x + y
 
     h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg,
-                 dense=(kind in ("attn_dense", "attn_cross")))
+                 dense=(kind in ("attn_dense", "attn_cross")),
+                 valid=ctx.get("valid"), use_kernel=want_cache)
     x = x + h
-    aux = aux + a
+    aux = L.add_aux(aux, a)
     if want_cache:
         cache = {"self": self_cache}
         if kind == "attn_cross":
@@ -340,7 +342,7 @@ def block_decode(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
     kv_cache.apply_decode_writes — carrying full updated caches through
     the scan ys forced per-iteration rematerialization of the whole ring
     (EXPERIMENTS.md §Perf iteration 3)."""
-    aux = jnp.float32(0.0)
+    aux = L.zero_aux(cfg)
     cur = ctx["cur"]
     if kind in ("mamba", "rglru"):
         mixer = L.mamba_mixer if kind == "mamba" else L.rglru_mixer
@@ -349,7 +351,7 @@ def block_decode(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         x = x + y
         if kind == "rglru":
             h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, dense=True)
-            x, aux = x + h, aux + a
+            x, aux = x + h, L.add_aux(aux, a)
         return x, state, aux
 
     if kind == "cross":
@@ -359,7 +361,7 @@ def block_decode(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         y, _ = reader(p["cross"], h, cache["cross"], cfg)
         x = x + y
         h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, dense=True)
-        return x + h, {"cross": None}, aux + a
+        return x + h, {"cross": None}, L.add_aux(aux, a)
 
     window = cfg.window_for(kind)
     pages = ctx.get("pages")
@@ -388,8 +390,9 @@ def block_decode(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         updates["cross"] = None
 
     h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg,
-                 dense=(kind in ("attn_dense", "attn_cross")))
-    return x + h, updates, aux + a
+                 dense=(kind in ("attn_dense", "attn_cross")),
+                 valid=ctx.get("valid"), use_kernel=True)
+    return x + h, updates, L.add_aux(aux, a)
 
 
 # ---------------------------------------------------------------------------
@@ -413,7 +416,7 @@ def block_verify(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         raise NotImplementedError(
             f"speculative verify is unsupported for recurrent "
             f"{kind!r} blocks")
-    aux = jnp.float32(0.0)
+    aux = L.zero_aux(cfg)
     cur, feed_mask = ctx["cur"], ctx["feed_mask"]
     if kind == "cross":
         h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
@@ -423,7 +426,7 @@ def block_verify(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         x = x + y
         h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg,
                      dense=True)
-        return x + h, {"cross": None}, aux + a
+        return x + h, {"cross": None}, L.add_aux(aux, a)
 
     window = cfg.window_for(kind)
     pages = ctx.get("pages")
@@ -454,8 +457,9 @@ def block_verify(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         updates["cross"] = None
 
     h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg,
-                 dense=(kind in ("attn_dense", "attn_cross")))
-    return x + h, updates, aux + a
+                 dense=(kind in ("attn_dense", "attn_cross")),
+                 valid=feed_mask, use_kernel=True)
+    return x + h, updates, L.add_aux(aux, a)
 
 
 # ---------------------------------------------------------------------------
@@ -471,7 +475,8 @@ def _layer_layout(cfg: ModelConfig):
 def run_stack(cfg: ModelConfig, params: Params, x: jax.Array, ctx: dict,
               caches: Params | None, *, decode: bool = False,
               verify: bool = False):
-    """Apply the whole stack.  Returns (x, new_caches, aux)."""
+    """Apply the whole stack.  Returns (x, new_caches, aux), aux summed
+    over the layers as :func:`layers.zero_aux` builds it."""
     prefix, pattern, suffix, n_per = _layer_layout(cfg)
     if verify:
         apply_fn = block_verify
@@ -480,7 +485,7 @@ def run_stack(cfg: ModelConfig, params: Params, x: jax.Array, ctx: dict,
     else:
         apply_fn = partial(block_full, want_cache=caches is not None)
     want_cache = caches is not None
-    aux = jnp.float32(0.0)
+    aux = L.zero_aux(cfg)
     new_caches: Params = {"prefix": [], "blocks": None, "suffix": []}
 
     def run_one(kind, p, x, c):
@@ -491,7 +496,7 @@ def run_stack(cfg: ModelConfig, params: Params, x: jax.Array, ctx: dict,
     for i, kind in enumerate(prefix):
         c_in = caches["prefix"][i] if (decode and want_cache) else None
         x, c, a = run_one(kind, params["prefix"][i], x, c_in)
-        aux = aux + a
+        aux = L.add_aux(aux, a)
         new_caches["prefix"].append(c)
 
     if n_per > 0:
@@ -503,7 +508,7 @@ def run_stack(cfg: ModelConfig, params: Params, x: jax.Array, ctx: dict,
             for j, kind in enumerate(pattern):
                 c_in = period_caches[j] if decode else None
                 x, c, a = run_one(kind, period_params[j], x, c_in)
-                aux = aux + a
+                aux = L.add_aux(aux, a)
                 outs.append(c)
             return (x, aux), tuple(outs)
 
@@ -519,7 +524,7 @@ def run_stack(cfg: ModelConfig, params: Params, x: jax.Array, ctx: dict,
     for i, kind in enumerate(suffix):
         c_in = caches["suffix"][i] if (decode and want_cache) else None
         x, c, a = run_one(kind, params["suffix"][i], x, c_in)
-        aux = aux + a
+        aux = L.add_aux(aux, a)
         new_caches["suffix"].append(c)
 
     if want_cache:
@@ -554,7 +559,8 @@ def encode(cfg: ModelConfig, params: Params, frames: jax.Array) -> jax.Array:
 
 def forward_hidden(cfg: ModelConfig, params: Params, tokens: jax.Array,
                    source: jax.Array | None = None):
-    """Training forward: tokens (B, T) -> hidden (B, T, d), aux loss."""
+    """Training forward: tokens (B, T) -> hidden (B, T, d), aux loss
+    (a float32 scalar)."""
     B, T = tokens.shape
     if cfg.encoder_decoder and source is not None:
         source = encode(cfg, params, source)
@@ -565,7 +571,7 @@ def forward_hidden(cfg: ModelConfig, params: Params, tokens: jax.Array,
     }
     x = embed_tokens(cfg, params, tokens)
     x, _, aux = run_stack(cfg, params, x, ctx, caches=None)
-    return L.rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
+    return L.rmsnorm(x, params["final_norm"], cfg.norm_eps), aux["loss"]
 
 
 def _lm_head_weight(cfg: ModelConfig, params: Params) -> jax.Array:
@@ -652,10 +658,11 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
     B, T = tokens.shape
     if cfg.encoder_decoder and source is not None:
         source = encode(cfg, params, source)
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
     ctx = {
-        "positions": jnp.broadcast_to(jnp.arange(T), (B, T)),
+        "positions": positions,
         "lengths": lengths, "source": source, "max_len": max_len,
-        "mesh": mesh,
+        "mesh": mesh, "valid": positions < lengths[:, None],
     }
     x = embed_tokens(cfg, params, tokens)
     x, caches, _ = run_stack(cfg, params, x, ctx, caches={})
@@ -668,7 +675,8 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
 def decode_step(cfg: ModelConfig, params: Params, caches: Params,
                 tokens: jax.Array, cur: jax.Array,
                 active: jax.Array | None = None, *,
-                cache_shardings=None, pages=None, mesh=None):
+                cache_shardings=None, pages=None, mesh=None,
+                moe_counts: bool = False):
     """One decode step.  tokens: (B,) int32, cur: (B,) absolute positions.
     ``active`` (B,) bool masks cache writes for idle batch rows (serving
     slots between requests).  ``cache_shardings`` (optional NamedSharding
@@ -677,15 +685,22 @@ def decode_step(cfg: ModelConfig, params: Params, caches: Params,
     (ptab (B, n_slot_pages) int32, page_size) switches reads and the
     deferred write to the page-major pool layout.  ``mesh`` (closure
     capture, never a traced argument) lets the pallas decode readers run
-    under shard_map over the mesh's "model" axis.  Returns
-    (logits (B, V), new caches)."""
+    under shard_map over the mesh's "model" axis.  ``active`` also keeps
+    idle rows out of MoE routing.  Returns (logits (B, V), new caches),
+    and with ``moe_counts`` (cfg.moe only) the step's routing counters
+    summed over the MoE layers, (len(layers.MOE_COUNTERS),) int32."""
     x = embed_tokens(cfg, params, tokens[:, None])
-    ctx = {"cur": cur, "pages": pages, "mesh": mesh}
-    x, updates, _ = run_stack(cfg, params, x, ctx, caches=caches, decode=True)
+    ctx = {"cur": cur, "pages": pages, "mesh": mesh,
+           "valid": None if active is None else active[:, None]}
+    x, updates, aux = run_stack(cfg, params, x, ctx, caches=caches,
+                                decode=True)
     caches = KC.apply_decode_writes(caches, updates, cur, active, pages=pages)
     caches = KC.constrain_caches(caches, cache_shardings)
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return logits_for(cfg, params, x)[:, 0], caches
+    logits = logits_for(cfg, params, x)[:, 0]
+    if moe_counts:
+        return logits, caches, aux["moe"]
+    return logits, caches
 
 
 def verify_step(cfg: ModelConfig, params: Params, caches: Params,

@@ -73,6 +73,7 @@ import numpy as np
 
 from repro.launch.mesh import single_device_mesh
 from repro.models import kv_cache as KC
+from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.serving import draft as D
@@ -540,6 +541,9 @@ class Engine:
         self.prefill_tokens = 0      # ... their real prompt tokens
         self.prefill_positions = 0   # ... their bucketed rows x length
         self.prefill_calls_saved = 0  # admissions served without a prefill
+        # decode-window MoE routing sums (layers.MOE_COUNTERS), harvested
+        # with the window's status; zero for a dense FFN
+        self.moe_decode = dict.fromkeys(L.MOE_COUNTERS, 0)
         self._preempted: deque[PreemptedRecord] = deque()
 
         # -- overlapped-pipeline state (inert when overlap=False) --------
@@ -902,10 +906,11 @@ class Engine:
                 stepping = st["act"] & ~stalled
                 pages = ((st["ptab"], page_size)
                          if page_size is not None else None)
-                logits, cache = T.decode_step(
+                out = T.decode_step(
                     cfg, params, cache, tok_in, st["cur"], stepping,
                     cache_shardings=cache_shardings, pages=pages,
-                    mesh=mesh)
+                    mesh=mesh, moe_counts=cfg.moe is not None)
+                logits, cache = out[:2]
                 ks = S.split_keys(st["keys"])
                 sampled = S.sample_tokens(logits, st["temp"], st["top_k"],
                                           st["top_p"], ks[:, 1],
@@ -930,6 +935,10 @@ class Engine:
                        "bpos": st["bpos"] + feeding.astype(st["bpos"].dtype),
                        "left": left2}
                 n_act = stepping.astype(jnp.int32).sum()
+                if cfg.moe is not None:
+                    # the step's MoE routing counters ride beside n_act
+                    # into the window's status
+                    n_act = jnp.concatenate([n_act[None], out[2]])
                 if continuous:
                     return ((cache, st2, seq),
                             (sampled, emit, sw_seq, sw_slot, n_act))
@@ -2478,7 +2487,7 @@ class Engine:
         if self.continuous:
             parts.append(sw_seq)
             parts.append(sw_slot)
-        parts.append(n_act.sum().reshape(1))
+        parts.append(n_act.sum(axis=0).reshape(-1))
         status = jnp.concatenate(parts)
         self._inflight.append(InflightWindow(
             index=self._dispatch_index, status=status, toks=toks,
@@ -2524,6 +2533,7 @@ class Engine:
             sw_slot = status[off + K: off + 2 * K]
             off += 2 * K
         self._act_iters += int(status[off])
+        self._count_moe(status[off + 1:])
         # snapshot the PRE-install slot->request map and the in-window
         # swap list BEFORE bookkeeping mutates them: the backlog item
         # credits each iteration's tokens to whoever held the slot then
@@ -2826,7 +2836,9 @@ class Engine:
         self._occupancy_sum += occ
         self._queue_depth_sum += qd
         if n_act is not None:
-            self._act_iters += int(np.asarray(n_act).sum())
+            n_act = np.asarray(n_act).reshape(toks.shape[0], -1)
+            self._act_iters += int(n_act[:, 0].sum())
+            self._count_moe(n_act[:, 1:].sum(axis=0))
         if acc is not None:
             self.draft_accepted += int(np.asarray(acc).sum())
             self.draft_proposed += int(np.asarray(prop).sum())
@@ -2843,6 +2855,12 @@ class Engine:
         for slot, r in enumerate(slot_req):
             if r is not None and not self._st["act"][slot]:
                 self._finish(slot)
+
+    def _count_moe(self, sums):
+        """Fold a harvested window's MoE routing sums (empty for a dense
+        FFN) into ``moe_decode``."""
+        for name, v in zip(L.MOE_COUNTERS, sums):
+            self.moe_decode[name] += int(v)
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
         """Drive until drained or ``max_steps`` COMPLETED windows, then
@@ -2904,7 +2922,11 @@ class Engine:
         bucketed positions of the prefills dispatched; ``slot_refills``
         lists the latest overlapped refills as (``time.perf_counter`` at
         the start of the ``engine.merge`` that filled the slot, seconds
-        since the ``engine.finish`` that emptied it)."""
+        since the ``engine.finish`` that emptied it).  The
+        ``moe_decode_*`` counters sum, over the decode windows harvested
+        and every MoE layer, the live slots' (token, expert) pairs routed
+        over all experts, the pairs whose expert is held here, and the
+        held experts with at least one pair (prefill is not counted)."""
         with self._mlock:
             tokens = self.tokens_emitted + self._admit_tokens
             windows_idle = self.windows_idle
@@ -2985,6 +3007,7 @@ class Engine:
             "prefill_tokens": prefill_tokens,
             "prefill_positions": prefill_positions,
             "prefill_calls_saved": prefill_calls_saved,
+            **self.moe_decode,
             "slot_refills": refills,
             "profile": profile,
             "ttft_s": ttft,

@@ -9,7 +9,10 @@ TPU compiler runs on the host and nothing executes.
 Widths are qwen3-4b's (32 query heads, 8 kv heads, head_dim 128); the
 latent cache is ReCalKV at keep ratio 0.5 with group size 4 (2 groups,
 rank 256), the engine's 8 slots and a 2048-position ring plus the
-deferred self column.
+deferred self column.  Qwen3-235B-A22B's share adds the latent decode
+kernel with one group of its 4 kv heads (64 query rows per group) at 32
+slots and a 4096-position ring, and the held-expert grouped matmul at its
+widths (d 4096, expert width 1536, 16 experts held).
 """
 
 from __future__ import annotations
@@ -24,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
+from repro.kernels.expert_gmm import expert_gmm
 from repro.kernels.flash_prefill import flash_prefill_attention
 from repro.kernels.latent_decode import (latent_decode_attention,
                                          latent_decode_attention_mq,
@@ -41,6 +45,8 @@ BF, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 # latent kernels as one kv head per group with identity reconstruction.
 DENSE = (8, DH, 4, 1)
 LATENT = (2, 256, 16, 4)
+# Qwen3-235B-A22B: 64 query heads over 4 kv heads, one group of 4
+LATENT_G1 = (1, 256, 64, 4)
 
 
 @pytest.fixture(scope="module")
@@ -72,7 +78,7 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _ring(geom, quant=False, nq=None):
+def _ring(geom, quant=False, nq=None, B=B, S=S):
     G, r, Hg, s = geom
     cols = S if nq is None else S + nq
     rows = Hg if nq is None else nq * Hg
@@ -105,6 +111,8 @@ KN = ((DH,), F32)
 CASES = {
     "dense_decode": (latent_decode_attention, _ring(DENSE), False),
     "latent_decode": (latent_decode_attention, _ring(LATENT), True),
+    "latent_decode_one_group": (latent_decode_attention,
+                                _ring(LATENT_G1, B=32, S=4096 + 1), True),
     "int8_decode": (latent_decode_attention_quant, _ring(LATENT, True), True),
     "dense_verify": (latent_decode_attention_mq, _ring(DENSE, nq=NQ), False),
     "latent_verify": (latent_decode_attention_mq, _ring(LATENT, nq=NQ), True),
@@ -160,3 +168,28 @@ def test_prefill_compiles_on_a_sharded_mesh(hv, dv, topo,
 
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (rows, K, N): the 32 x 8 decode rows and the 4096 x 8 prefill bucket
+# through the gate/up (d -> f) and down (f -> d) products
+EXPERT_CASES = {
+    "decode_gate_up": (32 * 8, 4096, 1536),
+    "decode_down": (32 * 8, 1536, 4096),
+    "prefill_gate_up": (4096 * 8, 4096, 1536),
+    "prefill_down": (4096 * 8, 1536, 4096),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_CASES))
+def test_expert_gmm_compiles_for_v5e(name, one_chip, no_persistent_cache):
+    m, k, n = EXPERT_CASES[name]
+    args = [jax.ShapeDtypeStruct((m, k), BF, sharding=one_chip),
+            jax.ShapeDtypeStruct((16, k, n), BF, sharding=one_chip),
+            jax.ShapeDtypeStruct((16,), I32, sharding=one_chip)]
+
+    def fn(x, w, g):
+        return expert_gmm(x, w, g, interpret=False)
+
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "expert_gmm" in text
