@@ -10,6 +10,13 @@ Empty groups get no work item and rows past the last group no row tile;
 the item count is a traced grid bound, so one compile serves every
 routing.  The contraction is whole in one block (no k loop): decode rows
 are few, and the weight read is the work.
+
+The weights may be one layer's ``(G, K, N)`` or a scanned stack's whole
+``(L, G, K, N)`` with a traced ``layer``, which is scalar-prefetched and
+picks the blocks at ``(layer, group, 0, j)``: a layer scan then hands the
+kernel its loop-invariant stack, and no slice of it is copied out before
+the call (a custom call cannot read a slice of a larger buffer).  One
+layer is the stack ``rhs[None]`` at layer 0.
 """
 
 from __future__ import annotations
@@ -55,8 +62,8 @@ def work_items(group_sizes: jax.Array, m_tiles: int, tm: int):
             item_tile.astype(jnp.int32), tiles.sum().astype(jnp.int32))
 
 
-def _kernel(starts_ref, ends_ref, group_ref, tile_ref, x_ref, w_ref, o_ref,
-            *, tm):
+def _kernel(layer_ref, starts_ref, ends_ref, group_ref, tile_ref, x_ref,
+            w_ref, o_ref, *, tm):
     i = pl.program_id(1)
     g = group_ref[i]
     row = tile_ref[i] * tm + jax.lax.broadcasted_iota(jnp.int32,
@@ -69,14 +76,17 @@ def _kernel(starts_ref, ends_ref, group_ref, tile_ref, x_ref, w_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
-def expert_gmm(lhs, rhs, group_sizes, *, block_m: int = 128,
+def expert_gmm(lhs, rhs, group_sizes, *, layer=None, block_m: int = 128,
                interpret: bool = False):
-    """lhs (m, K) rows sorted by group; rhs (G, K, N); group_sizes (G,)
-    int32 with sum <= m.  Returns (m, N) in lhs's dtype (float32
-    accumulation): row r of group g is lhs[r] @ rhs[g]; rows past the last
-    group are zero."""
+    """lhs (m, K) rows sorted by group; rhs (G, K, N), or (L, G, K, N)
+    with ``layer`` a traced int32 scalar; group_sizes (G,) int32 with sum
+    <= m.  Returns (m, N) in lhs's dtype (float32 accumulation): row r of
+    group g is lhs[r] @ rhs[g] (rhs[layer, g]); rows past the last group
+    are zero."""
+    if layer is None:
+        rhs, layer = rhs[None], 0
     m, k = lhs.shape
-    n = rhs.shape[2]
+    n = rhs.shape[3]
     tm = min(block_m, -(-m // 16) * 16)
     mp = -(-m // tm) * tm
     if mp != m:
@@ -87,25 +97,26 @@ def expert_gmm(lhs, rhs, group_sizes, *, block_m: int = 128,
         group_sizes.astype(jnp.int32), m_tiles, tm)
     # at least one item, whose rows mask to nothing when no group has rows
     n_items = jnp.maximum(n_items, 1)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     out = pl.pallas_call(
         functools.partial(_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(n // tn, n_items),
             in_specs=[
-                pl.BlockSpec((tm, k), lambda j, i, s, e, g, t: (t[i], 0)),
-                pl.BlockSpec((None, k, tn),
-                             lambda j, i, s, e, g, t: (g[i], 0, j)),
+                pl.BlockSpec((tm, k), lambda j, i, l, s, e, g, t: (t[i], 0)),
+                pl.BlockSpec((None, None, k, tn),
+                             lambda j, i, l, s, e, g, t: (l[0], g[i], 0, j)),
             ],
             out_specs=pl.BlockSpec((tm, tn),
-                                   lambda j, i, s, e, g, t: (t[i], j)),
+                                   lambda j, i, l, s, e, g, t: (t[i], j)),
         ),
         out_shape=jax.ShapeDtypeStruct((mp, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="expert_gmm",
-    )(starts, ends, item_group, item_tile, lhs, rhs)
+    )(layer, starts, ends, item_group, item_tile, lhs, rhs)
     rows = jax.lax.broadcasted_iota(jnp.int32, (mp, 1), 0)
     out = jnp.where(rows < ends[-1], out, 0)
     return out[:m] if mp != m else out

@@ -729,12 +729,14 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int | None = None,
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def expert_gmm(lhs, rhs, group_sizes, *, interpret: bool | None = None):
+def expert_gmm(lhs, rhs, group_sizes, *, layer=None,
+               interpret: bool | None = None):
     """Grouped matmul over held experts (see kernels/expert_gmm.py): lhs
-    (m, K) rows sorted by expert, rhs (G, K, N), group_sizes (G,) int32.
-    Runs on one device; an expert-parallel mesh would exchange rows
-    first, which this layer does not do."""
-    return _expert_gmm(lhs, rhs, group_sizes,
+    (m, K) rows sorted by expert, group_sizes (G,) int32, and rhs one
+    layer's (G, K, N) or a layer stack's (L, G, K, N) read in place at the
+    traced int32 ``layer``.  Runs on one device; an expert-parallel mesh
+    would exchange rows first, which this layer does not do."""
+    return _expert_gmm(lhs, rhs, group_sizes, layer=layer,
                        interpret=_resolve_interpret(interpret))
 
 

@@ -80,9 +80,12 @@ def flash_prefill_attention(q, k, v, *, causal=True, window=None, scale=None):
     return o.reshape(B, T, H, v.shape[-1])
 
 
-def expert_gmm(lhs, rhs, group_sizes):
+def expert_gmm(lhs, rhs, group_sizes, *, layer=None):
     """Reference grouped matmul: row r of group g is lhs[r] @ rhs[g] in
-    float32; rows past the last group are zero."""
+    float32 (rhs[layer, g] when rhs stacks layers); rows past the last
+    group are zero."""
+    if layer is not None:
+        rhs = rhs[layer]
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
     row = jnp.arange(lhs.shape[0])[:, None]

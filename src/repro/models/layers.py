@@ -382,10 +382,13 @@ def swiglu(p: Params, x: jax.Array) -> jax.Array:
     return (jax.nn.silu(x @ p["wg"]) * (x @ p["wi"])) @ p["wo"]
 
 
-def _grouped(rows, w, sizes, use_kernel: bool):
-    """rows (m, K) sorted by held expert @ w (E_held, K, N) per group."""
+def _grouped(rows, w, sizes, use_kernel: bool, layer=None):
+    """rows (m, K) sorted by held expert @ w (E_held, K, N) per group, or
+    @ w[layer] of a stacked (L, E_held, K, N)."""
     if use_kernel:
-        return kops.expert_gmm(rows, w, sizes)
+        return kops.expert_gmm(rows, w, sizes, layer=layer)
+    if layer is not None:
+        w = w[layer]
     return jax.lax.ragged_dot(rows, w, sizes,
                               preferred_element_type=jnp.float32
                               ).astype(rows.dtype)
@@ -393,7 +396,8 @@ def _grouped(rows, w, sizes, use_kernel: bool):
 
 def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
             valid: jax.Array | None = None,
-            use_kernel: bool = False) -> tuple[jax.Array, Params]:
+            use_kernel: bool = False,
+            layer: jax.Array | None = None) -> tuple[jax.Array, Params]:
     """Dropless top-k MoE over the experts this device holds.  Returns
     (out, aux).
 
@@ -404,7 +408,8 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
     result is this device's share of the layer.  ``valid`` (B, T) bool
     marks real rows; pad rows and idle slots are routed to no expert.
     ``use_kernel`` runs the grouped products in the Pallas kernel, else in
-    ``jax.lax.ragged_dot``."""
+    ``jax.lax.ragged_dot``.  With ``layer`` (traced int32), ``wg``/``wi``/
+    ``wo`` are a layer scan's whole stacks and layer ``layer`` is used."""
     m = cfg.moe
     B, T, d = x.shape
     N = B * T
@@ -435,11 +440,11 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
     sizes = jnp.zeros((n_held + 1,), jnp.int32).at[group].add(1)[:n_held]
 
     rows = jnp.take(xt, order // k, axis=0)                      # (N*k, d)
-    h = _grouped(rows, p["wg"], sizes, use_kernel)
-    u = _grouped(rows, p["wi"], sizes, use_kernel)
+    h = _grouped(rows, p["wg"], sizes, use_kernel, layer)
+    u = _grouped(rows, p["wi"], sizes, use_kernel, layer)
     hu = (jax.nn.silu(h.astype(jnp.float32))
           * u.astype(jnp.float32)).astype(x.dtype)
-    y = _grouped(hu, p["wo"], sizes, use_kernel)                 # (N*k, d)
+    y = _grouped(hu, p["wo"], sizes, use_kernel, layer)          # (N*k, d)
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(N * k, dtype=order.dtype))
     y = jnp.take(y, back, axis=0).reshape(N, k, d).astype(jnp.float32)
@@ -455,13 +460,15 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
 
 def ffn(p: Params, x: jax.Array, cfg: ModelConfig, dense: bool,
         valid: jax.Array | None = None,
-        use_kernel: bool = False) -> tuple[jax.Array, Params]:
-    """The block's FFN: dense SwiGLU (``valid`` ignored) or, under cfg.moe
-    and not ``dense``, :func:`moe_ffn`.  Returns (out, aux) with aux as
-    :func:`zero_aux` builds it."""
+        use_kernel: bool = False,
+        layer: jax.Array | None = None) -> tuple[jax.Array, Params]:
+    """The block's FFN: dense SwiGLU (``valid`` and ``layer`` ignored) or,
+    under cfg.moe and not ``dense``, :func:`moe_ffn`.  Returns (out, aux)
+    with aux as :func:`zero_aux` builds it."""
     if cfg.moe is not None and not dense:
         return moe_ffn(p, x, cfg, valid,
-                       use_kernel=use_kernel and cfg.attn_backend == "pallas")
+                       use_kernel=use_kernel and cfg.attn_backend == "pallas",
+                       layer=layer)
     return swiglu(p, x), zero_aux(cfg)
 
 

@@ -31,6 +31,11 @@ from repro.models.config import ModelConfig
 
 Params = dict[str, Any]
 
+# Self-attention block kinds whose FFN is the held-expert MoE under
+# cfg.moe (the others' is dense), and that FFN's per-expert weight leaves.
+MOE_FFN_KINDS = ("attn", "local")
+EXPERT_LEAVES = ("wg", "wi", "wo")
+
 
 # ---------------------------------------------------------------------------
 # Initialization
@@ -175,7 +180,7 @@ def init_block_params(cfg: ModelConfig, kind: str, key, stack=None) -> Params:
         return {"ln1": norm(), "attn": init_attn_params(cfg, ks[0], stack=stack),
                 "lnx": norm(), "cross": init_attn_params(cfg, ks[1], cross=True, stack=stack),
                 "ln2": norm(), "mlp": init_ffn_params(cfg, ks[2], dense=True, stack=stack)}
-    dense = kind == "attn_dense"
+    dense = kind not in MOE_FFN_KINDS
     return {"ln1": norm(), "attn": init_attn_params(cfg, ks[0], stack=stack),
             "ln2": norm(), "mlp": init_ffn_params(cfg, ks[1], dense=dense, stack=stack)}
 
@@ -302,8 +307,9 @@ def block_full(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         x = x + y
 
     h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg,
-                 dense=(kind in ("attn_dense", "attn_cross")),
-                 valid=ctx.get("valid"), use_kernel=want_cache)
+                 dense=kind not in MOE_FFN_KINDS,
+                 valid=ctx.get("valid"), use_kernel=want_cache,
+                 layer=ctx.get("layer"))
     x = x + h
     aux = L.add_aux(aux, a)
     if want_cache:
@@ -390,8 +396,9 @@ def block_decode(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         updates["cross"] = None
 
     h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg,
-                 dense=(kind in ("attn_dense", "attn_cross")),
-                 valid=ctx.get("valid"), use_kernel=True)
+                 dense=kind not in MOE_FFN_KINDS,
+                 valid=ctx.get("valid"), use_kernel=True,
+                 layer=ctx.get("layer"))
     return x + h, updates, L.add_aux(aux, a)
 
 
@@ -457,8 +464,8 @@ def block_verify(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
         updates["cross"] = None
 
     h, a = L.ffn(p["mlp"], L.rmsnorm(x, p["ln2"], cfg.norm_eps), cfg,
-                 dense=(kind in ("attn_dense", "attn_cross")),
-                 valid=feed_mask, use_kernel=True)
+                 dense=kind not in MOE_FFN_KINDS,
+                 valid=feed_mask, use_kernel=True, layer=ctx.get("layer"))
     return x + h, updates, L.add_aux(aux, a)
 
 
@@ -488,7 +495,7 @@ def run_stack(cfg: ModelConfig, params: Params, x: jax.Array, ctx: dict,
     aux = L.zero_aux(cfg)
     new_caches: Params = {"prefix": [], "blocks": None, "suffix": []}
 
-    def run_one(kind, p, x, c):
+    def run_one(kind, p, x, c, ctx=ctx):
         if decode:
             return apply_fn(cfg, kind, p, x, c, ctx)
         return apply_fn(cfg, kind, p, x, ctx)
@@ -500,24 +507,45 @@ def run_stack(cfg: ModelConfig, params: Params, x: jax.Array, ctx: dict,
         new_caches["prefix"].append(c)
 
     if n_per > 0:
+        # In inference passes (decode, verify, prefill with a cache) a
+        # scanned MoE block's expert stacks stay out of the scanned xs: the
+        # loop closes over them whole and the kernel reads the period's
+        # experts in place at ctx["layer"].  Scanned, each would be sliced
+        # out into a copy per layer and call.  Training keeps the slices,
+        # whose gradients are per layer.
+        experts = {}
+        if cfg.moe is not None and (decode or want_cache):
+            experts = {j: {n: params["blocks"][j]["mlp"][n]
+                           for n in EXPERT_LEAVES}
+                       for j, kind in enumerate(pattern)
+                       if kind in MOE_FFN_KINDS}
+        blocks = tuple(
+            dict(blk, mlp={n: v for n, v in blk["mlp"].items()
+                           if n not in EXPERT_LEAVES})
+            if j in experts else blk
+            for j, blk in enumerate(params["blocks"]))
+
         def body(carry, xs):
             x, aux = carry
-            period_params = xs[0]
-            period_caches = xs[1]
+            period_params, period_caches, layer = xs
+            ctx_p = dict(ctx, layer=layer) if experts else ctx
             outs = []
             for j, kind in enumerate(pattern):
+                p = period_params[j]
+                if j in experts:
+                    p = dict(p, mlp=dict(p["mlp"], **experts[j]))
                 c_in = period_caches[j] if decode else None
-                x, c, a = run_one(kind, period_params[j], x, c_in)
+                x, c, a = run_one(kind, p, x, c_in, ctx_p)
                 aux = L.add_aux(aux, a)
                 outs.append(c)
             return (x, aux), tuple(outs)
 
         if (not decode) and cfg.remat and not want_cache:
             body = jax.checkpoint(body)
-        xs = (params["blocks"],
-              caches["blocks"] if (decode and want_cache) else None)
-        if xs[1] is None:
-            xs = (params["blocks"], tuple(None for _ in pattern))
+        period_caches = (caches["blocks"] if (decode and want_cache)
+                         else tuple(None for _ in pattern))
+        xs = (blocks, period_caches,
+              jnp.arange(n_per, dtype=jnp.int32) if experts else None)
         (x, aux), scan_caches = jax.lax.scan(body, (x, aux), xs)
         new_caches["blocks"] = scan_caches if want_cache else None
 

@@ -152,6 +152,90 @@ def test_expert_gmm_bf16_rows():
                                rtol=1e-2, atol=5e-2)
 
 
+def stacked_case(case):
+    """Rows, a 3-layer (L, G, K, N) weight stack and group sizes: a
+    GMM_CASES routing in float32, or bf16 rows whose first row tile is
+    shared by two groups."""
+    if case == "bf16_rows":
+        (sizes, m), dt = ([20, 0, 21], 48), jnp.bfloat16
+    else:
+        (sizes, m), dt = GMM_CASES[case], jnp.float32
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(m, 64)), dt)
+    w = jnp.asarray(rng.normal(size=(3, len(sizes), 64, 256)), dt)
+    return x, w, jnp.asarray(sizes, jnp.int32)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("case", sorted(GMM_CASES) + ["bf16_rows"])
+def test_stacked_expert_gmm_reads_the_layer_in_place(case, layer):
+    """Over a layer stack at a traced layer the kernel is the one-layer
+    kernel over that layer's slice, bit for bit, and its oracle's
+    match."""
+    x, w, gs = stacked_case(case)
+    got = expert_gmm(x, w, gs, layer=jnp.int32(layer), block_m=32,
+                     interpret=True)
+    one = expert_gmm(x, w[layer], gs, block_m=32, interpret=True)
+    assert got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+    want = ref.expert_gmm(x, w, gs, layer=layer)
+    np.testing.assert_array_equal(np.asarray(want),
+                                  np.asarray(ref.expert_gmm(x, w[layer], gs)))
+    tol = ({"rtol": 1e-2, "atol": 5e-2} if case == "bf16_rows"
+           else {"rtol": 1e-5, "atol": 1e-4})
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), **tol)
+
+
+def scan_consts(fn, *args):
+    """Shapes of the operands the first layer scan of ``fn`` closes
+    over (its loop-invariant operands)."""
+    eqn = next(e for e in jax.make_jaxpr(fn)(*args).eqns
+               if e.primitive.name == "scan")
+    return [v.aval.shape for v in eqn.invars[:eqn.params["num_consts"]]]
+
+
+@pytest.mark.parametrize("pattern", [("attn",), ("attn_dense", "attn")],
+                         ids=["moe", "dense_then_moe"])
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_scanned_stack_matches_the_per_layer_form(backend, pattern):
+    """Two scanned periods, whose MoE blocks hand their whole expert
+    stacks to every layer, give the logits and MoE counters of the same
+    layers unscanned, each with its own (G, K, N) slice, in prefill and
+    decode."""
+    cfg = dataclasses.replace(moe_cfg(backend, first_held=2, num_held=8),
+                              num_layers=2 * len(pattern),
+                              layer_pattern=pattern)
+    params = T.init_params(cfg, jax.random.PRNGKey(4))
+    flat = dataclasses.replace(cfg, scan_layers=False)
+    per_layer = dict(params, blocks=(), prefix=tuple(
+        jax.tree.map(lambda a, i=i: a[i], blk)
+        for i in range(2) for blk in params["blocks"]))
+    rng = np.random.default_rng(5)
+    toks = jnp.asarray(rng.integers(0, 64, (3, 6)), jnp.int32)
+    lens = jnp.asarray([6, 4, 1])
+    nxt = jnp.asarray(rng.integers(0, 64, 3), jnp.int32)
+    active = jnp.asarray([True, True, False])
+    outs = []
+    for c, p in ((cfg, params), (flat, per_layer)):
+        lg, caches = jax.jit(functools.partial(T.prefill, c, max_len=16))(
+            p, toks, lens)
+        step = functools.partial(T.decode_step, c, moe_counts=True)
+        outs.append((lg, caches) + jax.jit(step)(p, caches, nxt, lens,
+                                                 active))
+    (pre, caches, dec, _, counts), (pre1, _, dec1, _, counts1) = outs
+    # the scanned decode step closes over the (periods, held, K, N) stacks
+    step = functools.partial(T.decode_step, cfg, moe_counts=True)
+    consts = scan_consts(step, params, caches, nxt, lens, active)
+    assert consts.count((2, 8, D, F)) == 2 and (2, 8, F, D) in consts
+    np.testing.assert_allclose(np.asarray(pre), np.asarray(pre1),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(dec), np.asarray(dec1),
+                               rtol=1e-6, atol=1e-6)
+    assert [int(v) for v in counts] == [int(v) for v in counts1]
+    assert int(counts[0]) == 2 * 2 * K
+
+
 # -- through the engine ------------------------------------------------------
 
 def _tiny_family():

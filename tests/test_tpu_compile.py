@@ -11,12 +11,14 @@ latent cache is ReCalKV at keep ratio 0.5 with group size 4 (2 groups,
 rank 256), the engine's 8 slots and a 2048-position ring plus the
 deferred self column.  Qwen3-235B-A22B's share adds the latent decode
 kernel with one group of its 4 kv heads (64 query rows per group) at 32
-slots and a 4096-position ring, and the held-expert grouped matmul at its
-widths (d 4096, expert width 1536, 16 experts held).
+slots and a 4096-position ring, the held-expert grouped matmul at its
+widths (d 4096, expert width 1536, 16 experts held), over one layer's
+experts and over a layer stack, and its whole decode step at 2 layers.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -35,6 +37,8 @@ from repro.kernels.latent_decode import (latent_decode_attention,
                                          latent_decode_attention_paged)
 from repro.kernels.latent_decode_q import (latent_decode_attention_mq_quant,
                                            latent_decode_attention_quant)
+from repro.models import transformer as T
+from repro.models.config import ModelConfig, MoEConfig, ReCalKVRuntime
 
 B, S, DH, NQ = 8, 2048 + 1, 128, 3
 HALF = DH // 2
@@ -170,26 +174,71 @@ def test_prefill_compiles_on_a_sharded_mesh(hv, dv, topo,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# (rows, K, N): the 32 x 8 decode rows and the 4096 x 8 prefill bucket
-# through the gate/up (d -> f) and down (f -> d) products
+# (rows, K, N[, layers]): the 32 x 8 decode rows and the 4096 x 8 prefill
+# bucket through the gate/up (d -> f) and down (f -> d) products; with
+# layers, the weights are an 8-layer stack read at a traced layer
 EXPERT_CASES = {
     "decode_gate_up": (32 * 8, 4096, 1536),
     "decode_down": (32 * 8, 1536, 4096),
     "prefill_gate_up": (4096 * 8, 4096, 1536),
     "prefill_down": (4096 * 8, 1536, 4096),
+    "decode_gate_up_stacked": (32 * 8, 4096, 1536, 8),
+    "decode_down_stacked": (32 * 8, 1536, 4096, 8),
+    "prefill_down_stacked": (4096 * 8, 1536, 4096, 8),
 }
 
 
 @pytest.mark.parametrize("name", sorted(EXPERT_CASES))
 def test_expert_gmm_compiles_for_v5e(name, one_chip, no_persistent_cache):
-    m, k, n = EXPERT_CASES[name]
+    m, k, n, *stack = EXPERT_CASES[name]
     args = [jax.ShapeDtypeStruct((m, k), BF, sharding=one_chip),
-            jax.ShapeDtypeStruct((16, k, n), BF, sharding=one_chip),
+            jax.ShapeDtypeStruct((*stack, 16, k, n), BF, sharding=one_chip),
             jax.ShapeDtypeStruct((16,), I32, sharding=one_chip)]
+    if stack:
+        args.append(jax.ShapeDtypeStruct((), I32, sharding=one_chip))
 
-    def fn(x, w, g):
-        return expert_gmm(x, w, g, interpret=False)
+    def fn(x, w, g, layer=None):
+        return expert_gmm(x, w, g, layer=layer, interpret=False)
 
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "expert_gmm" in text
+
+
+def test_moe_decode_step_reads_held_experts_in_place(one_chip,
+                                                     no_persistent_cache,
+                                                     monkeypatch):
+    """The decode step of Qwen3-235B-A22B's share (2 scanned layers, 32
+    slots, ring 4096, caches donated) hands ``expert_gmm`` the layer
+    stack itself: no instruction has one layer's held-expert shape, which
+    is what a slice copied out of the scanned stack would have."""
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = ModelConfig(
+        name="qwen3-235b-a22b-share", family="moe", num_layers=2,
+        d_model=4096, num_heads=64, num_kv_heads=4, d_head=DH, d_ff=1536,
+        vocab_size=151936, qk_norm=True,
+        moe=MoEConfig(num_experts=128, top_k=8, d_expert=1536, num_held=16),
+        recalkv=ReCalKVRuntime(rank_k=256, rank_v=256, group_size=4),
+        attn_backend="pallas", dtype=BF)
+    slots, ring = 32, 4096
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(functools.partial(T.init_params, cfg),
+                                   jax.random.PRNGKey(0)))
+    caches = placed(jax.eval_shape(
+        functools.partial(T.init_decode_cache, cfg, slots, ring)))
+    assert params["blocks"][0]["mlp"]["wg"].shape == (2, 16, 4096, 1536)
+    step = jax.jit(functools.partial(T.decode_step, cfg),
+                   donate_argnums=(1,))
+    text = step.lower(params, caches,
+                      jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip),
+                      jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip),
+                      jax.ShapeDtypeStruct((slots,), jnp.bool_,
+                                           sharding=one_chip),
+                      ).compile().as_text()
+    assert "expert_gmm" in text and "tpu_custom_call" in text
+    for shape in ("[16,4096,1536]", "[16,1536,4096]"):
+        assert f"bf16{shape}" not in text, shape
